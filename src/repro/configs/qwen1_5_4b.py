@@ -1,6 +1,6 @@
 """qwen1.5-4b [dense] — MHA with QKV bias.
 
-40L d_model=2560 20H (kv=20) d_ff=6912 vocab=151936 [hf:Qwen/Qwen1.5-0.5B].
+40L d_model=2560 20H (kv=20) d_ff=6912 vocab=151936 [hf:Qwen/Qwen1.5-4B].
 """
 from repro.config import ModelConfig
 

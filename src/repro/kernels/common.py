@@ -1,5 +1,8 @@
-"""Shared Pallas flash-attention machinery (TPU target, interpret-mode
-validated on CPU).
+"""Shared Pallas flash-attention machinery and the interpreter choice.
+
+The kernels compile for the TPU (Mosaic). On any other backend they run
+in the Pallas interpreter, which the CPU tests use to check them against
+their jnp oracles; `use_interpreter` makes that choice for every kernel.
 
 One partial-softmax flash kernel covers the framework's attention hot
 spots; wrappers in tree_attention/ and decode_attention/ specialize block
@@ -8,10 +11,17 @@ flash-decoding trick generalized to CoSine's tree verification).
 
 The kernel emits *unnormalized* (acc, m, l) so multiple KV sources can be
 merged exactly before the final normalization (see merge_partials).
+
+TPU layout: the last two dims of every block must be multiples of
+(8, 128) or span the array. Query positions and the m/l statistics
+therefore carry a trailing unit axis ((rows, 1) blocks) and key
+positions a middle unit axis ((1, block_k) blocks), so the row axis
+lies on sublanes and the key axis on lanes.
 """
 from __future__ import annotations
 
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -21,44 +31,57 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
-def _make_kernel(*, scale, causal, window, nk, has_mask,
-                 block_q, block_k, dk, dv):
-    def kernel(qpos_ref, kpos_ref, q_ref, k_ref, v_ref, *rest):
-        if has_mask:
-            mask_ref, acc_out, m_out, l_out, m_s, l_s, acc_s = rest
-        else:
-            acc_out, m_out, l_out, m_s, l_s, acc_s = rest
-            mask_ref = None
-        kb = pl.program_id(3)
+def use_interpreter(interpret: Optional[bool] = None) -> bool:
+    """Whether a Pallas kernel runs in the interpreter: an explicit
+    `interpret` wins; None means compiled on a TPU backend and
+    interpreted everywhere else."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
+
+
+def _make_kernel(*, scale, causal, window, nk, has_mask, block_q, dv,
+                 kv_axis=3, n_prefetch=0):
+    """Flash-attention body over one (block_q x block_k) tile. The KV
+    block index is grid axis `kv_axis` (sequential); the first
+    `n_prefetch` refs are scalar-prefetch operands that only feed the
+    BlockSpec index maps."""
+    def kernel(*refs):
+        qpos_ref, kpos_ref, q_ref, k_ref, v_ref = refs[n_prefetch:
+                                                       n_prefetch + 5]
+        rest = refs[n_prefetch + 5:]
+        mask_ref = rest[0] if has_mask else None
+        acc_out, m_out, l_out, m_s, l_s, acc_s = rest[int(has_mask):]
+        kb = pl.program_id(kv_axis)
 
         @pl.when(kb == 0)
         def _init():
-            m_s[...] = jnp.full((block_q,), NEG_INF, jnp.float32)
-            l_s[...] = jnp.zeros((block_q,), jnp.float32)
+            m_s[...] = jnp.full((block_q, 1), NEG_INF, jnp.float32)
+            l_s[...] = jnp.zeros((block_q, 1), jnp.float32)
             acc_s[...] = jnp.zeros((block_q, dv), jnp.float32)
 
         q = q_ref[0, 0].astype(jnp.float32)          # (bq, Dk)
         k = k_ref[0, 0].astype(jnp.float32)          # (bk, Dk)
         v = v_ref[0, 0].astype(jnp.float32)          # (bk, Dv)
-        qpos = qpos_ref[0]                           # (bq,)
-        kpos = kpos_ref[0]                           # (bk,)
+        qpos = qpos_ref[0]                           # (bq, 1)
+        kpos = kpos_ref[0]                           # (1, bk)
 
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-        valid = (kpos >= 0)[None, :]
+        valid = kpos >= 0
         if causal:
-            valid = valid & (kpos[None, :] <= qpos[:, None])
+            valid = valid & (kpos <= qpos)
         if window > 0:
-            valid = valid & (qpos[:, None] - kpos[None, :] < window)
+            valid = valid & (qpos - kpos < window)
         if mask_ref is not None:
-            valid = valid & mask_ref[0]
+            valid = valid & (mask_ref[0] != 0)
         s = jnp.where(valid, s, NEG_INF)
 
         m_prev = m_s[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
         corr = jnp.exp(m_prev - m_new)
-        l_s[...] = l_s[...] * corr + p.sum(axis=1)
-        acc_s[...] = acc_s[...] * corr[:, None] + jax.lax.dot_general(
+        l_s[...] = l_s[...] * corr + p.sum(axis=1, keepdims=True)
+        acc_s[...] = acc_s[...] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())))
         m_s[...] = m_new
 
@@ -69,6 +92,13 @@ def _make_kernel(*, scale, causal, window, nk, has_mask,
             l_out[0, 0] = l_s[...]
 
     return kernel
+
+
+def flash_scratch(block_q, dv):
+    """VMEM carries (m, l, acc) of the online softmax across KV blocks."""
+    return [pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32)]
 
 
 def _pad_to(x, size, axis, value=0):
@@ -82,13 +112,14 @@ def _pad_to(x, size, axis, value=0):
 
 def flash_attention_partial(q, k, v, q_pos, k_pos, *, scale, causal=True,
                             window=0, mask=None, block_q=128, block_k=128,
-                            interpret=True):
+                            interpret=None):
     """Blocked flash attention returning unnormalized partials.
 
     q: (B, Hkv, R, Dk) — R query rows (tokens x GQA group, pre-expanded)
     k: (B, Hkv, S, Dk); v: (B, Hkv, S, Dv)
     q_pos: (B, R); k_pos: (B, S); mask: optional (B, R, S) bool
     Returns acc (B, Hkv, R, Dv) f32, m (B, Hkv, R) f32, l (B, Hkv, R) f32.
+    On a TPU, block_k must be a multiple of 128 unless it covers S.
     """
     B, H, R, Dk = q.shape
     S = k.shape[2]
@@ -101,17 +132,17 @@ def flash_attention_partial(q, k, v, q_pos, k_pos, *, scale, causal=True,
     q = _pad_to(q, Rp, 2)
     k = _pad_to(k, Sp, 2)
     v = _pad_to(v, Sp, 2)
-    q_pos = _pad_to(q_pos.astype(jnp.int32), Rp, 1)
-    k_pos = _pad_to(k_pos.astype(jnp.int32), Sp, 1, value=-1)
+    q_pos = _pad_to(q_pos.astype(jnp.int32), Rp, 1)[:, :, None]
+    k_pos = _pad_to(k_pos.astype(jnp.int32), Sp, 1, value=-1)[:, None, :]
     if mask is not None:
-        mask = _pad_to(_pad_to(mask, Rp, 1), Sp, 2)
+        mask = _pad_to(_pad_to(mask.astype(jnp.int32), Rp, 1), Sp, 2)
 
     nq, nk = Rp // block_q, Sp // block_k
     grid = (B, H, nq, nk)
 
     in_specs = [
-        pl.BlockSpec((1, block_q), lambda b, h, iq, ik: (b, iq)),
-        pl.BlockSpec((1, block_k), lambda b, h, iq, ik: (b, ik)),
+        pl.BlockSpec((1, block_q, 1), lambda b, h, iq, ik: (b, iq, 0)),
+        pl.BlockSpec((1, 1, block_k), lambda b, h, iq, ik: (b, 0, ik)),
         pl.BlockSpec((1, 1, block_q, Dk), lambda b, h, iq, ik: (b, h, iq, 0)),
         pl.BlockSpec((1, 1, block_k, Dk), lambda b, h, iq, ik: (b, h, ik, 0)),
         pl.BlockSpec((1, 1, block_k, Dv), lambda b, h, iq, ik: (b, h, ik, 0)),
@@ -124,32 +155,27 @@ def flash_attention_partial(q, k, v, q_pos, k_pos, *, scale, causal=True,
 
     out_specs = [
         pl.BlockSpec((1, 1, block_q, Dv), lambda b, h, iq, ik: (b, h, iq, 0)),
-        pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq)),
-        pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq)),
+        pl.BlockSpec((1, 1, block_q, 1), lambda b, h, iq, ik: (b, h, iq, 0)),
+        pl.BlockSpec((1, 1, block_q, 1), lambda b, h, iq, ik: (b, h, iq, 0)),
     ]
     out_shape = [
         jax.ShapeDtypeStruct((B, H, Rp, Dv), jnp.float32),
-        jax.ShapeDtypeStruct((B, H, Rp), jnp.float32),
-        jax.ShapeDtypeStruct((B, H, Rp), jnp.float32),
+        jax.ShapeDtypeStruct((B, H, Rp, 1), jnp.float32),
+        jax.ShapeDtypeStruct((B, H, Rp, 1), jnp.float32),
     ]
 
     kernel = _make_kernel(scale=scale, causal=causal, window=window, nk=nk,
-                          has_mask=mask is not None, block_q=block_q,
-                          block_k=block_k, dk=Dk, dv=Dv)
+                          has_mask=mask is not None, block_q=block_q, dv=Dv)
     acc, m, l = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q, Dv), jnp.float32),
-        ],
-        interpret=interpret,
+        scratch_shapes=flash_scratch(block_q, Dv),
+        interpret=use_interpreter(interpret),
     )(*args)
-    return acc[:, :, :R], m[:, :, :R], l[:, :, :R]
+    return acc[:, :, :R], m[:, :, :R, 0], l[:, :, :R, 0]
 
 
 def merge_partials(parts):

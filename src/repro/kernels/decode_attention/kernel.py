@@ -21,57 +21,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import (NEG_INF, _pad_to, flash_attention_partial,
-                                  merge_partials, _make_kernel)
+from repro.kernels.common import (_make_kernel, _pad_to, flash_attention_partial,
+                                  flash_scratch, merge_partials,
+                                  use_interpreter)
 
 __all__ = ["flash_attention_partial", "merge_partials", "_make_kernel",
            "paged_flash_decode"]
 
 
-def _make_paged_kernel(*, scale, window, nv, block_q, dv):
-    def kernel(tbl_ref, qpos_ref, kpos_ref, q_ref, k_ref, v_ref,
-               acc_out, m_out, l_out, m_s, l_s, acc_s):
-        del tbl_ref  # consumed by the BlockSpec index maps
-        lp = pl.program_id(2)
-
-        @pl.when(lp == 0)
-        def _init():
-            m_s[...] = jnp.full((block_q,), NEG_INF, jnp.float32)
-            l_s[...] = jnp.zeros((block_q,), jnp.float32)
-            acc_s[...] = jnp.zeros((block_q, dv), jnp.float32)
-
-        q = q_ref[0, 0].astype(jnp.float32)          # (bq, Dk)
-        k = k_ref[0, 0].astype(jnp.float32)          # (ps, Dk)
-        v = v_ref[0, 0].astype(jnp.float32)          # (ps, Dv)
-        qpos = qpos_ref[0]                           # (bq,)
-        kpos = kpos_ref[0]                           # (ps,)
-
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ()))) * scale
-        valid = (kpos >= 0)[None, :] & (kpos[None, :] <= qpos[:, None])
-        if window > 0:
-            valid = valid & (qpos[:, None] - kpos[None, :] < window)
-        s = jnp.where(valid, s, NEG_INF)
-
-        m_prev = m_s[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_s[...] = l_s[...] * corr + p.sum(axis=1)
-        acc_s[...] = acc_s[...] * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())))
-        m_s[...] = m_new
-
-        @pl.when(lp == nv - 1)
-        def _out():
-            acc_out[0, 0] = acc_s[...]
-            m_out[0, 0] = m_s[...]
-            l_out[0, 0] = l_s[...]
-
-    return kernel
-
-
 def paged_flash_decode(q, k_pages, v_pages, page_pos, q_pos, block_tables,
-                       *, scale, window=0, interpret=True):
+                       *, scale, window=0, interpret=None):
     """Decode-over-pool flash attention partials (unnormalized).
 
     q: (B, Hkv, G, Dk) one token's queries (G = GQA group rows);
@@ -91,20 +50,21 @@ def paged_flash_decode(q, k_pages, v_pages, page_pos, q_pos, block_tables,
     partial first, as tree verification does).
     """
     B, H, G, Dk = q.shape
-    ps = k_pages.shape[2]
+    P, ps = page_pos.shape
     Dv = v_pages.shape[3]
     nv = block_tables.shape[1]
     bq = max(8, G)
 
     q = _pad_to(q, bq, 2)
-    qpos_rows = jnp.broadcast_to(q_pos.astype(jnp.int32)[:, None], (B, bq))
+    qpos_rows = jnp.broadcast_to(q_pos.astype(jnp.int32)[:, None, None],
+                                 (B, bq, 1))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(B, H, nv),
         in_specs=[
-            pl.BlockSpec((1, bq), lambda b, h, i, tbl: (b, 0)),
-            pl.BlockSpec((1, ps), lambda b, h, i, tbl: (tbl[b, i], 0)),
+            pl.BlockSpec((1, bq, 1), lambda b, h, i, tbl: (b, 0, 0)),
+            pl.BlockSpec((1, 1, ps), lambda b, h, i, tbl: (tbl[b, i], 0, 0)),
             pl.BlockSpec((1, 1, bq, Dk), lambda b, h, i, tbl: (b, h, 0, 0)),
             pl.BlockSpec((1, 1, ps, Dk),
                          lambda b, h, i, tbl: (tbl[b, i], h, 0, 0)),
@@ -113,26 +73,23 @@ def paged_flash_decode(q, k_pages, v_pages, page_pos, q_pos, block_tables,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, Dv), lambda b, h, i, tbl: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, tbl: (b, h, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, i, tbl: (b, h, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, tbl: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, i, tbl: (b, h, 0, 0)),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq, Dv), jnp.float32),
-        ],
+        scratch_shapes=flash_scratch(bq, Dv),
     )
-    kernel = _make_paged_kernel(scale=scale, window=window, nv=nv,
-                                block_q=bq, dv=Dv)
+    kernel = _make_kernel(scale=scale, causal=True, window=window, nk=nv,
+                          has_mask=False, block_q=bq, dv=Dv, kv_axis=2,
+                          n_prefetch=1)
     acc, m, l = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((B, H, bq, Dv), jnp.float32),
-            jax.ShapeDtypeStruct((B, H, bq), jnp.float32),
-            jax.ShapeDtypeStruct((B, H, bq), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, bq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, bq, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=use_interpreter(interpret),
     )(block_tables.astype(jnp.int32), qpos_rows,
-      page_pos.astype(jnp.int32), q, k_pages, v_pages)
-    return acc[:, :, :G], m[:, :, :G], l[:, :, :G]
+      page_pos.astype(jnp.int32).reshape(P, 1, ps), q, k_pages, v_pages)
+    return acc[:, :, :G], m[:, :, :G, 0], l[:, :, :G, 0]

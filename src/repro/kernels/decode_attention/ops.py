@@ -14,7 +14,7 @@ from repro.kernels.decode_attention.kernel import paged_flash_decode
 
 @partial(jax.jit, static_argnames=("scale", "window", "interpret", "block_k"))
 def decode_attention(q, k_cache, v_cache, cache_pos, q_pos, *, scale,
-                     window=0, interpret=True, block_k=512):
+                     window=0, interpret=None, block_k=512):
     """Same signature/semantics as ref.decode_attention_ref (docs there)."""
     B, H, G, Dk = q.shape
     qpos_rows = jnp.broadcast_to(q_pos[:, None], (B, G))
@@ -27,7 +27,7 @@ def decode_attention(q, k_cache, v_cache, cache_pos, q_pos, *, scale,
 
 @partial(jax.jit, static_argnames=("scale", "window", "interpret", "block_k"))
 def decode_attention_slots(q, k_cache, v_cache, cache_pos, q_pos, slot_idx,
-                           *, scale, window=0, interpret=True, block_k=512):
+                           *, scale, window=0, interpret=None, block_k=512):
     """Slot-indexed flash decode: the KV cache holds a resident slot
     *pool* (batch axis S_pool >= B) and only rows `slot_idx` (B,) are
     attended — the read-side counterpart of the model's in-place
@@ -48,7 +48,7 @@ def decode_attention_slots(q, k_cache, v_cache, cache_pos, q_pos, slot_idx,
 
 @partial(jax.jit, static_argnames=("scale", "window", "interpret"))
 def decode_attention_paged(q, k_pages, v_pages, page_pos, q_pos,
-                           block_tables, *, scale, window=0, interpret=True):
+                           block_tables, *, scale, window=0, interpret=None):
     """Paged flash decode: the KV cache is a physical page *pool*
     (DESIGN.md §2.8) and each request reads only the pages named by its
     block table. Unlike `decode_attention_slots` (where XLA gathers the

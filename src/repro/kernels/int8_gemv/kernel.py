@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import use_interpreter
+
 
 def _int8_gemv_kernel(x_ref, w_ref, s_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)        # (B, K) activations
@@ -36,7 +38,7 @@ def _int8_gemv_kernel(x_ref, w_ref, s_ref, o_ref):
 
 @partial(jax.jit, static_argnames=("block_n", "interpret"))
 def int8_gemv_call(x, w8, scale, *, block_n: int = 128,
-                   interpret: bool = False):
+                   interpret=None):
     """Raw pallas_call on pre-padded operands.
 
     x: (B, K) float; w8: (K, N) int8; scale: (1, N) f32 with
@@ -58,5 +60,5 @@ def int8_gemv_call(x, w8, scale, *, block_n: int = 128,
         ],
         out_specs=pl.BlockSpec((B, block_n), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((B, N), jnp.float32),
-        interpret=interpret,
+        interpret=use_interpreter(interpret),
     )(x, w8, scale)

@@ -2,7 +2,7 @@
 
 Two paths behind one contract (y = (x @ w8) * scale, f32 accumulate):
 
-* :func:`int8_gemv` — the Pallas TPU kernel (interpret-mode on CPU),
+* :func:`int8_gemv` — the Pallas TPU kernel (interpreted off-TPU),
   padding arbitrary shapes to the int8 tile grid. Bitwise-equal to
   `ref.int8_gemv_ref` on tile-aligned shapes (K % 32, N % 128, the
   wrapper pads B); padded-K shapes are allclose (the zero-padded tail
@@ -34,7 +34,7 @@ def _pad_axis(a, mult, axis):
 
 
 @partial(jax.jit, static_argnames=("block_n", "interpret"))
-def int8_gemv(x, w8, scale, *, block_n: int = 128, interpret: bool = False):
+def int8_gemv(x, w8, scale, *, block_n: int = 128, interpret=None):
     """Fused dequant-GEMV: x (B, K) float, w8 (K, N) int8, scale (N,)
     or (1, N) f32 per-output-channel. Returns (B, N) f32."""
     B, K = x.shape

@@ -1,11 +1,16 @@
 """Mamba2 SSD chunked-scan Pallas kernel (TPU target).
 
 Layout: grid (B, n_head_blocks, n_chunks); the chunk dimension is
-sequential ("arbitrary") and the (head_block, P, N) recurrent state lives
+sequential ("arbitrary") and each head's (N, P) recurrent state lives
 in VMEM scratch across chunk iterations — the inter-chunk recurrence never
 round-trips HBM. Within a chunk the dual ("attention-like") form runs on
-the MXU: (Q x N) x (N x Q) score matmuls and (Q x Q) x (Q x P) output
-matmuls, Q = chunk_size (default 128, MXU-aligned).
+the MXU as plain 2-D matmuls per head: (Q x N) x (N x Q) scores and
+(Q x Q) x (Q x P) outputs, Q = chunk_size (default 128, MXU-aligned).
+
+Operands are head-major so every block's last two dims tile as (8, 128)
+or span the array: x / C as (Q, P) / (Q, N) rows, B pre-transposed to
+(N, Q), and dt plus the chunk-local cumulative decay as (Q, 1) columns
+and a (1, Q) row (the wrapper computes the cumulative sum).
 """
 from __future__ import annotations
 
@@ -15,9 +20,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import use_interpreter
 
-def _make_ssd_kernel(*, Q, hb, P, N, nc):
-    def kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, init_ref,
+
+def _make_ssd_kernel(*, Q, hb, nc):
+    def kernel(x_ref, dt_ref, acol_ref, arow_ref, bt_ref, c_ref, init_ref,
                y_ref, final_ref, state_s):
         ci = pl.program_id(2)
 
@@ -25,31 +32,32 @@ def _make_ssd_kernel(*, Q, hb, P, N, nc):
         def _init():
             state_s[...] = init_ref[0].astype(jnp.float32)
 
-        x = x_ref[0].astype(jnp.float32)          # (Q, hb, P)
-        dt = dt_ref[0].astype(jnp.float32)        # (Q, hb)
-        A = a_ref[...].astype(jnp.float32)        # (hb,)
-        Bm = b_ref[0].astype(jnp.float32)         # (Q, hb, N)
-        Cm = c_ref[0].astype(jnp.float32)         # (Q, hb, N)
+        causal = (jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+                  >= jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1))
+        for h in range(hb):
+            x = x_ref[0, h].astype(jnp.float32)       # (Q, P)
+            dt = dt_ref[0, h]                         # (Q, 1)
+            acol = acol_ref[0, h]                     # (Q, 1) cum dt*A
+            arow = arow_ref[0, h]                     # (1, Q)
+            bt = bt_ref[0, h]                         # (N, Q)
+            c = c_ref[0, h]                           # (Q, N)
+            state = state_s[h]                        # (N, P)
 
-        dA = dt * A[None, :]                      # (Q, hb) negative
-        dAc = jnp.cumsum(dA, axis=0)              # (Q, hb)
+            lmat = jnp.where(causal, jnp.exp(acol - arow), 0.0)
+            scores = jnp.dot(c, bt, preferred_element_type=jnp.float32) * lmat
+            y = jnp.dot(scores, x * dt, preferred_element_type=jnp.float32)
+            y = y + jnp.dot(c, state,
+                            preferred_element_type=jnp.float32) * jnp.exp(acol)
+            y_ref[0, h] = y.astype(y_ref.dtype)
 
-        seg = dAc[:, None, :] - dAc[None, :, :]   # (Q, Q, hb)
-        causal = jnp.tril(jnp.ones((Q, Q), jnp.bool_))
-        Lmat = jnp.where(causal[:, :, None], jnp.exp(seg), 0.0)
-        scores = jnp.einsum("qhn,khn->qkh", Cm, Bm) * Lmat
-        xdt = x * dt[:, :, None]
-        y_intra = jnp.einsum("qkh,khp->qhp", scores, xdt)
-
-        state = state_s[...]                       # (hb, P, N)
-        y_inter = jnp.einsum("qhn,hpn->qhp", Cm, state) \
-            * jnp.exp(dAc)[:, :, None]
-        y_ref[0] = (y_intra + y_inter).astype(y_ref.dtype)
-
-        chunk_decay = jnp.exp(dAc[Q - 1])          # (hb,)
-        decay_to_end = jnp.exp(dAc[Q - 1][None, :] - dAc)  # (Q, hb)
-        state_add = jnp.einsum("qhn,qh,qhp->hpn", Bm, decay_to_end * dt, x)
-        state_s[...] = state * chunk_decay[:, None, None] + state_add
+            last = acol[Q - 1:Q, :]                   # (1, 1)
+            w = jnp.exp(last - acol) * dt             # (Q, 1)
+            # widen the chunk decay along lanes first: Mosaic cannot
+            # broadcast a (1, 1) value over sublanes and lanes at once
+            decay = jnp.exp(last + jnp.zeros((1, state.shape[1]),
+                                             jnp.float32))
+            state_s[h] = state * decay + jnp.dot(
+                bt, x * w, preferred_element_type=jnp.float32)
 
         @pl.when(ci == nc - 1)
         def _final():
@@ -58,39 +66,45 @@ def _make_ssd_kernel(*, Q, hb, P, N, nc):
     return kernel
 
 
-def ssd_scan_pallas(x, dt, A, Bh, Ch, chunk, initial_state,
-                    head_block: int = 8, interpret: bool = True):
-    """x: (b, L, H, P); dt: (b, L, H); A: (H,); Bh/Ch: (b, L, H, N)
-    (groups pre-broadcast to heads); initial_state: (b, H, P, N).
-    L must be a multiple of `chunk` (ops.py pads). Returns (y, final)."""
-    b, L, H, P = x.shape
-    N = Bh.shape[-1]
+def ssd_scan_pallas(x, dt, dA_cum, Bt, C, chunk, initial_state,
+                    head_block: int = 8, interpret=None):
+    """Head-major operands (ops.py builds them):
+    x: (b, H, L, P); dt: (b, H, L) f32; dA_cum: (b, H, L) f32 — the
+    cumulative sum of dt * A restarted at every chunk; Bt: (b, H, N, L)
+    and C: (b, H, L, N) f32 (groups pre-broadcast to heads);
+    initial_state: (b, H, N, P) f32. L must be a multiple of `chunk`.
+    Returns (y (b, H, L, P) in x.dtype, final (b, H, N, P) f32)."""
+    b, H, L, P = x.shape
+    N = Bt.shape[2]
     hb = min(head_block, H)
     assert H % hb == 0 and L % chunk == 0
     nc = L // chunk
     grid = (b, H // hb, nc)
+    Q = chunk
 
-    kernel = _make_ssd_kernel(Q=chunk, hb=hb, P=P, N=N, nc=nc)
+    kernel = _make_ssd_kernel(Q=Q, hb=hb, nc=nc)
     y, final = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, chunk, hb, P), lambda i, j, c: (i, c, j, 0)),
-            pl.BlockSpec((1, chunk, hb), lambda i, j, c: (i, c, j)),
-            pl.BlockSpec((hb,), lambda i, j, c: (j,)),
-            pl.BlockSpec((1, chunk, hb, N), lambda i, j, c: (i, c, j, 0)),
-            pl.BlockSpec((1, chunk, hb, N), lambda i, j, c: (i, c, j, 0)),
-            pl.BlockSpec((1, hb, P, N), lambda i, j, c: (i, j, 0, 0)),
+            pl.BlockSpec((1, hb, Q, P), lambda i, j, c: (i, j, c, 0)),
+            pl.BlockSpec((1, hb, Q, 1), lambda i, j, c: (i, j, c, 0)),
+            pl.BlockSpec((1, hb, Q, 1), lambda i, j, c: (i, j, c, 0)),
+            pl.BlockSpec((1, hb, 1, Q), lambda i, j, c: (i, j, 0, c)),
+            pl.BlockSpec((1, hb, N, Q), lambda i, j, c: (i, j, 0, c)),
+            pl.BlockSpec((1, hb, Q, N), lambda i, j, c: (i, j, c, 0)),
+            pl.BlockSpec((1, hb, N, P), lambda i, j, c: (i, j, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, hb, P), lambda i, j, c: (i, c, j, 0)),
-            pl.BlockSpec((1, hb, P, N), lambda i, j, c: (i, j, 0, 0)),
+            pl.BlockSpec((1, hb, Q, P), lambda i, j, c: (i, j, c, 0)),
+            pl.BlockSpec((1, hb, N, P), lambda i, j, c: (i, j, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, L, H, P), x.dtype),
-            jax.ShapeDtypeStruct((b, H, P, N), jnp.float32),
+            jax.ShapeDtypeStruct((b, H, L, P), x.dtype),
+            jax.ShapeDtypeStruct((b, H, N, P), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((hb, P, N), jnp.float32)],
-        interpret=interpret,
-    )(x, dt, A, Bh, Ch, initial_state)
+        scratch_shapes=[pltpu.VMEM((hb, N, P), jnp.float32)],
+        interpret=use_interpreter(interpret),
+    )(x, dt[..., None], dA_cum[..., None], dA_cum[:, :, None, :], Bt, C,
+      initial_state)
     return y, final

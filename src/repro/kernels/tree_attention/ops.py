@@ -13,7 +13,7 @@ from repro.kernels.common import flash_attention_partial, merge_partials
 @partial(jax.jit, static_argnames=("scale", "window", "interpret",
                                    "block_q", "block_k"))
 def tree_attention(q, k_cache, v_cache, cache_pos, k_seg, v_seg, q_pos,
-                   seg_mask, *, scale, window=0, interpret=True,
+                   seg_mask, *, scale, window=0, interpret=None,
                    block_q=128, block_k=128):
     """Same signature/semantics as ref.tree_attention_ref (docs there)."""
     hist = flash_attention_partial(

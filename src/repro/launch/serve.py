@@ -16,6 +16,7 @@ from repro.checkpoint.store import load_checkpoint
 from repro.config import CoSineConfig
 from repro.configs.drafters import tiny_drafter, tiny_target
 from repro.data.synthetic import DOMAINS, SyntheticCorpus
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serving.engine import STRATEGIES, SpeculativeEngine
 
 VOCAB = 96
@@ -55,6 +56,7 @@ def main():
     ap.add_argument("--draft-len", type=int, default=5)
     ap.add_argument("--drafters-per-request", type=int, default=2)
     args = ap.parse_args()
+    enable_compile_cache()
 
     corpus = SyntheticCorpus(VOCAB, seed=0, sharpness=120.0, support=5)
     target, drafters = build_models(args.ckpt_dir, corpus, args.train_steps)

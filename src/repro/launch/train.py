@@ -42,7 +42,9 @@ def train_model(cfg: ModelConfig, corpus: SyntheticCorpus,
     """Train (or fine-tune, if params given) on one domain or the mixture."""
     key = jax.random.PRNGKey(seed)
     if params is None:
-        params = M.init_params(key, cfg)
+        # master weights and optimizer state stay f32; the forward still
+        # computes in cfg.dtype
+        params = M.init_params(key, cfg.with_overrides(dtype="float32"))
     opt = get_optimizer(optimizer, lr)
     opt_state = opt.init(params)
     step_fn = jax.jit(make_train_step(cfg, opt, remat=False))

@@ -111,11 +111,22 @@ def _init_sublayer(key, spec: LayerSpec, cfg: ModelConfig):
     return p
 
 
+def _as_param_dtype(tree, cfg: ModelConfig):
+    """Cast floating leaves to the serving dtype `cfg.dtype`."""
+    dt = jnp.dtype(cfg.dtype)
+    return jax.tree.map(
+        lambda x: x.astype(dt) if jnp.issubdtype(x.dtype, jnp.floating)
+        else x, tree)
+
+
 def _init_stage(key, pattern, repeats, cfg: ModelConfig):
+    # cast inside the vmapped init: run eagerly, each stacked leaf is
+    # converted as soon as it is drawn, so the whole stage never exists
+    # in f32 at once (a 40-layer 4B stage would be ~12 GiB)
     def init_one(k):
         kk = jax.random.split(k, len(pattern))
-        return tuple(_init_sublayer(kk[j], pattern[j], cfg)
-                     for j in range(len(pattern)))
+        return _as_param_dtype(tuple(_init_sublayer(kk[j], pattern[j], cfg)
+                                     for j in range(len(pattern))), cfg)
     return jax.vmap(init_one)(jax.random.split(key, repeats))
 
 
@@ -131,6 +142,7 @@ def _init_encoder(key, cfg: ModelConfig):
 
 
 def init_params(key, cfg: ModelConfig):
+    """Seeded random weights in `cfg.dtype` (the serving dtype)."""
     ks = jax.random.split(key, 8)
     plan = layer_plan(cfg)
     params = {
@@ -157,7 +169,7 @@ def init_params(key, cfg: ModelConfig):
             "norm_e": norm_params(cfg, cfg.d_model),
             "layer": _init_sublayer(km[1], spec, cfg),
         }
-    return params
+    return _as_param_dtype(params, cfg)
 
 
 # ====================================================== caches
@@ -206,7 +218,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int,
             c = _sublayer_cache(pattern[j], cfg, batch, max_len, dtype,
                                 cross_len)
             per.append(jax.tree.map(
-                lambda x: jnp.broadcast_to(x, (reps,) + x.shape).copy(), c))
+                lambda x: jnp.broadcast_to(x, (reps,) + x.shape), c))
         stages.append(tuple(per))
     return {"stages": stages, "lengths": jnp.zeros((batch,), jnp.int32)}
 
@@ -369,7 +381,7 @@ def init_paged_cache(cfg: ModelConfig, batch: int, dtype=jnp.bfloat16, *,
                 c["cross"] = attn.make_kv_cache(batch, max(cross_len, 1),
                                                 cfg.n_kv_heads, hd, hd, dtype)
             per.append(jax.tree.map(
-                lambda x: jnp.broadcast_to(x, (reps,) + x.shape).copy(), c))
+                lambda x: jnp.broadcast_to(x, (reps,) + x.shape), c))
         stages.append(tuple(per))
     return {"stages": stages, "lengths": jnp.zeros((batch,), jnp.int32)}
 
@@ -602,7 +614,6 @@ def _scatter_stage_delta(scache, deltas, slot_idx, positions,
     block table to physical row page_view[b, c // ps] * ps + c % ps.
     The manager pre-allocates every page a write can touch, so writes
     never land on the NULL page (padding rows map to the scratch page)."""
-    bidx = slot_idx[:, None]
     out = []
     for cj, dj in zip(scache, deltas):
         nc = dict(cj)
@@ -631,13 +642,38 @@ def _scatter_stage_delta(scache, deltas, slot_idx, positions,
             else:                   # attention KV: new-token rows
                 C = pool_c["slot_pos"].shape[-1]
                 if key == "cross":  # full-row projections, columns 0..S
-                    scol = jnp.arange(d["slot_pos"].shape[-1])[None, :]
+                    S = d["slot_pos"].shape[-1]
+                    scol = jnp.broadcast_to(jnp.arange(S), (len(slot_idx), S))
                 else:               # ring placement, as in write_kv
                     scol = positions % C
-                nc[key] = {f: pool_c[f].at[:, bidx, scol].set(d[f])
-                           for f in pool_c}
+                nc[key] = _write_token_rows(pool_c, d, slot_idx, scol)
         out.append(nc)
     return tuple(out)
+
+
+def _write_token_rows(pools, rows, slot_idx, cols):
+    """pools[f][:, slot_idx[b], cols[b, t]] = rows[f][:, b, t] for every
+    field f and token (b, t), as one in-place dynamic_update_slice per
+    token. The equivalent scatter makes the TPU compiler relayout the
+    whole pool and back (two copies of a 1.76 GiB K stack for a 4B model,
+    which leaves no room for the model beside its cache); these writes
+    keep the pool where it is. Later tokens win on duplicate targets,
+    which only padding rows (the scratch slot) produce."""
+    B, T = cols.shape
+
+    def body(i, ps):
+        b, t = i // T, i % T
+        out = {}
+        for f, p in ps.items():
+            r = rows[f]
+            tail = (0,) * (r.ndim - 3)
+            row = jax.lax.dynamic_slice(r, (0, b, t) + tail,
+                                        (r.shape[0], 1, 1) + r.shape[3:])
+            out[f] = jax.lax.dynamic_update_slice(
+                p, row.astype(p.dtype), (0, slot_idx[b], cols[b, t]) + tail)
+        return out
+
+    return jax.lax.fori_loop(0, B * T, body, dict(pools))
 
 
 def _apply_stage(pattern, sparams, scache, x, positions, cfg: ModelConfig,

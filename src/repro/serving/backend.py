@@ -168,6 +168,11 @@ class ExecutionBackend(ABC):
         """Release the request's slots on the target and every drafter
         (completion, shed, or preemption). No-op for unknown rids."""
 
+    def sync(self) -> None:
+        """Wait for queued backend work and raise the first failure of
+        any of it, including work whose result nobody reads. No-op for
+        synchronous backends, whose failures raise at the call."""
+
     def shutdown(self) -> None:
         """Release backend resources (worker threads)."""
 
@@ -262,6 +267,10 @@ class AsyncJaxBackend(ExecutionBackend):
             max_workers=1, thread_name_prefix="verify-server")
         self.timeline: List[dict] = []
         self._timeline_pos = 0
+        # exceptions raised by server tasks, in order; some futures are
+        # never read (drops, prefills of shed requests), so the worker
+        # records every failure and `sync`/`shutdown` re-raise it
+        self._failures: List[BaseException] = []
 
     def now_ms(self) -> float:
         """Wall-clock ms since backend construction."""
@@ -277,6 +286,9 @@ class AsyncJaxBackend(ExecutionBackend):
             span["t0"] = self.now_ms()
             try:
                 return fn()
+            except BaseException as exc:
+                self._failures.append(exc)
+                raise
             finally:
                 span["t1"] = self.now_ms()
                 self.timeline.append(span)
@@ -375,9 +387,22 @@ class AsyncJaxBackend(ExecutionBackend):
         for d in self.drafters:
             d.extend_committed(committed)
 
+    def _raise_failure(self):
+        if self._failures:
+            exc, self._failures = self._failures[0], []
+            raise RuntimeError("a verification-server task failed") from exc
+
+    def sync(self):
+        """Wait until the server has run every queued task (FIFO, so one
+        barrier task suffices), then raise the first task failure."""
+        self._pool.submit(lambda: None).result()
+        self._raise_failure()
+
     def shutdown(self):
-        """Drain and join the verification server thread."""
+        """Drain and join the verification server thread; raise the
+        first task failure not yet raised by `sync`."""
         self._pool.shutdown(wait=True)
+        self._raise_failure()
 
 
 def make_backend(spec, target, drafter_specs, max_len: int,

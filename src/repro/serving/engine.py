@@ -1054,8 +1054,12 @@ class SpeculativeEngine:
                 self.on_commit(r, toks, self.clock_ms)
 
     def run(self, max_iterations: int = 10_000) -> ServeStats:
-        """Step until the pool drains; returns the run's ServeStats."""
+        """Step until the pool drains; returns the run's ServeStats.
+        Raises if any backend work failed, including the last async
+        commit and evictions whose results no step reads."""
         for _ in range(max_iterations):
             if self.step() is None:
                 break
+        self.backend.sync()
+        self._resolve_tails()
         return self.stats

@@ -433,13 +433,13 @@ class ModelRunner:
     """
 
     def __init__(self, cfg: ModelConfig, params, max_len: int = 512,
-                 cache_dtype=jnp.float32, n_slots: int = 8,
-                 paged: bool = False, page_size: int = 64,
+                 n_slots: int = 8, paged: bool = False, page_size: int = 64,
                  pool_pages: int = 0):
         self.cfg = cfg
         self.params = params
         self.max_len = max_len
-        self.cache_dtype = cache_dtype
+        # caches hold activations, so they take the model's compute dtype
+        cache_dtype = jnp.dtype(cfg.dtype)
         self.paged = paged
         if paged:
             self.slots: SlotCacheManager = PagedSlotCacheManager(
@@ -448,10 +448,15 @@ class ModelRunner:
         else:
             self.slots = SlotCacheManager(cfg, max_len, n_slots, cache_dtype)
         # routing prior embeddings: dequantized view for weight-only
-        # int8 params (the router works in f32 host space either way)
-        self.embed_np = np.asarray(
-            quantize.dequantize_weight(params["embed"])[: cfg.vocab],
-            np.float32)
+        # int8 params (the router works in f32 host space either way).
+        # Sliced and widened on the host: an f32 copy of a full-vocabulary
+        # table on the device would not fit beside a full-width model.
+        embed = jax.device_get(params["embed"])
+        if quantize.is_quantized(embed):
+            self.embed_np = (embed["w8"][: cfg.vocab].astype(np.float32)
+                             * embed["scale"][: cfg.vocab])
+        else:
+            self.embed_np = np.asarray(embed[: cfg.vocab], np.float32)
         # masked slot_extend writes issued by the prefill paths (the
         # burst-admission test asserts batched prefill issues fewer)
         self.n_prefill_writes = 0

@@ -291,3 +291,48 @@ def test_async_wallclock_monotone_and_streaming(models):
     for r in reqs:
         assert seen[r.rid] == list(r.generated)
         assert done_at[r.rid] is True
+
+
+# ---------------------------------------------------- async: no lost errors
+@pytest.mark.parametrize("failing", ["drop", "final_commit"])
+def test_async_server_failure_fails_run(models, failing):
+    """A verification-server task that raises makes `run()` raise, also
+    when nobody reads its future: the target-side drop on completion,
+    and the last commit of the run, whose tail logits no later
+    acceptance walk consumes."""
+    prompt, max_new = _prompts(1)[0], 6
+    eng = _engine(models, "attn", "cosine", backend="async")
+    boom = ValueError(failing)
+    tgt = eng.target
+    if failing == "drop":
+        def drop(rid):
+            raise boom
+        tgt.drop = drop
+    else:
+        extend = tgt.extend_committed
+
+        def extend_committed(rid_tokens):
+            out = extend(rid_tokens)
+            if any(tgt.length(r) >= len(prompt) + max_new
+                   for r in rid_tokens):
+                raise boom
+            return out
+        tgt.extend_committed = extend_committed
+    eng.submit(prompt, max_new_tokens=max_new)
+    with pytest.raises(RuntimeError) as err:
+        eng.run()
+    assert err.value.__cause__ is boom
+    eng.backend.shutdown()          # already reported: does not re-raise
+
+
+def test_async_shutdown_raises_unread_failure(models):
+    """A failed task whose future is never read surfaces at shutdown()."""
+    b = make_backend("async", models["attn"], models["drafters"], MAX_LEN)
+    boom = ValueError("unread")
+
+    def fail():
+        raise boom
+    b.submit_target("drop", fail)
+    with pytest.raises(RuntimeError) as err:
+        b.shutdown()
+    assert err.value.__cause__ is boom
