@@ -1,0 +1,9 @@
+"""Device idle share: 1 - (union of device-operation intervals) over the
+traced window, in percent."""
+
+
+def read(ctx):
+    tr = ctx["trace"]
+    if tr is None or tr["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
