@@ -1,10 +1,15 @@
 """Serving telemetry layer (DESIGN.md §2.6).
 
-Three pieces, all dependency-free and deterministic:
+Three pieces, deterministic on the simulated clocks:
 
   * `obs.trace`   — `Tracer`: per-request lifecycle + per-stage occupancy
                     spans built from instrumentation hooks in the serving
-                    stack (engine / pipeline / cluster / admission).
+                    stack (engine / pipeline / cluster / admission); and
+                    host regions (`Tracer.region`, names in `HOST_SPANS`):
+                    wall-clock spans of the served path's host work,
+                    written into the JAX profiler's trace and counted
+                    into the registry (``host.ms`` / ``host.self_ms`` /
+                    ``host.calls``).
   * `obs.metrics` — `MetricsRegistry`: counters, gauges and fixed-bucket
                     histograms — the single source behind `ServeStats`'
                     aggregates — plus the controller `DecisionLog`
@@ -18,6 +23,6 @@ must emit, so its measured overlap can be diffed against the
 discrete-event executor's prediction (ROADMAP headline item).
 """
 from repro.obs.metrics import DecisionLog, MetricsRegistry
-from repro.obs.trace import Span, Tracer
+from repro.obs.trace import HOST_SPANS, Span, Tracer
 
-__all__ = ["DecisionLog", "MetricsRegistry", "Span", "Tracer"]
+__all__ = ["DecisionLog", "HOST_SPANS", "MetricsRegistry", "Span", "Tracer"]

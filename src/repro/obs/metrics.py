@@ -8,7 +8,9 @@ back — no ad-hoc `total_x += ...` fields scattered across modules.
 Naming convention: dotted ``subsystem.metric[_unit]`` names with optional
 labels, e.g. ``verify.busy_ms``, ``serve.committed_tokens``,
 ``draft.node_tokens{node=3}``. Everything is plain Python floats/ints —
-no deps, no locks (the serving loop is single-threaded), and
+no deps, no locks (the serving loop is single-threaded; the wall-clock
+backend's verification-server thread writes only its own ``host.*``
+counters, which the engine thread never writes), and
 `to_dict()` is deterministically ordered so a metrics JSON export is
 byte-identical across same-seed runs.
 

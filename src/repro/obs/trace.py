@@ -24,17 +24,62 @@ future async wall-clock loop must satisfy against this executor.
 Memory is bounded by `max_spans` (a ring: oldest spans drop, the drop
 count is surfaced in the metrics export); with the cap unhit the trace
 is complete and determinism tests are unaffected.
+
+Host regions (`Tracer.region`) are the other record, kept out of the
+span deque: wall-clock spans of the host work inside a serving step,
+named from `HOST_SPANS`. Each one is a `jax.profiler.TraceAnnotation`,
+so while a profiler runs it lands on the calling thread's line of the
+device trace, on the device trace's clock; and each one adds its
+inclusive ms, its self ms (inclusive minus child regions on the same
+thread) and one call to the registry's ``host.ms{span=}``,
+``host.self_ms{span=}`` and ``host.calls{span=}`` counters. Regions are
+live only on a tracer given a registry (the wall-clock backend's): the
+simulated clocks' export stays free of wall time.
 """
 from __future__ import annotations
 
+import contextlib
+import gc
+import threading
+import time
 from collections import deque
 from dataclasses import dataclass
-from typing import Deque, List, Optional, Tuple
+from typing import Deque, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 # span categories
 STAGE = "stage"          # serial-resource occupancy (verify / draft nodes)
 CLUSTER = "cluster"      # cluster-level activity (fuse, transit)
 LIFECYCLE = "lifecycle"  # per-request state transitions (instants)
+
+# host regions of the served path (wall clock), declared once:
+# the engine thread's spans; all but engine.lull are top level and
+# together tile a step
+ENGINE_SPANS = (
+    "engine.plan",            # pending scan, observation, admission, plan
+    "engine.lull",            # in engine.plan: sleep to the next arrival
+    "engine.prefill",         # cold requests: target enqueue, drafters
+    "engine.draft",           # one cohort's drafting (draft-ahead, redraft)
+    "engine.verify_dispatch",  # pad_trees and the verify enqueue
+    "engine.verify_wait",     # blocked on the server's verify future
+    "engine.logits_readback",  # slice and host copy of verify logits
+    "engine.resolve",         # blocked on queued prefill / commit futures
+    "engine.walk",            # argmax, acceptance walk, router update
+    "engine.commit",          # commit enqueue, drafter commit
+    "engine.finalize",        # accounting, _finalize, gamma feedback
+    "engine.reconcile",       # draft-ahead survival (a redraft nests)
+)
+# children of engine.draft; the per-node ones carry `node`
+DRAFT_SPANS = ("draft.snapshot", "draft.extend", "draft.decode",
+               "draft.sample", "draft.fuse", "draft.tree")
+# the drafter work a node does on the device's behalf
+NODE_WORK_SPANS = ("draft.snapshot", "draft.extend", "draft.decode")
+GC_SPAN = "host.gc"           # Python collector pauses, engine thread
+# the verification-server thread, one per task kind
+SERVER_SPANS = ("server.verify", "server.prefill", "server.commit",
+                "server.drop")
+HOST_SPANS = ENGINE_SPANS + DRAFT_SPANS + (GC_SPAN,) + SERVER_SPANS
 
 
 @dataclass(frozen=True)
@@ -69,14 +114,53 @@ class Span:
         return default
 
 
+class _Region:
+    """One live host region (see `Tracer.region`)."""
+    __slots__ = ("tracer", "name", "node", "ann", "t0", "child")
+
+    def __init__(self, tracer: "Tracer", name: str, args: dict):
+        self.tracer, self.name = tracer, name
+        self.node = args.get("node")
+        self.ann = TraceAnnotation(name, **args)
+
+    def __enter__(self):
+        self.ann.__enter__()
+        self.tracer._stack().append(self)
+        self.child = 0.0
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        ms = (time.perf_counter() - self.t0) * 1e3
+        stack = self.tracer._stack()
+        stack.pop()
+        if stack:
+            stack[-1].child += ms
+        self.tracer._count(self.name, ms, ms - self.child, self.node)
+        self.ann.__exit__(*exc)
+        return False
+
+
+_OFF = contextlib.nullcontext()
+
+
 class Tracer:
-    def __init__(self, enabled: bool = True, max_spans: int = 0):
+    def __init__(self, enabled: bool = True, max_spans: int = 0,
+                 metrics=None):
         self.enabled = enabled
         self.max_spans = int(max_spans)
         self.spans: Deque[Span] = deque(
             maxlen=self.max_spans if self.max_spans > 0 else None)
         self._seq = 0
         self.n_dropped = 0
+        # host regions count into this registry; None keeps them inert
+        self.metrics = metrics
+        self.regions_live = bool(enabled) and metrics is not None
+        self._local = threading.local()
+        # (span, node) -> its (ms, self_ms, calls[, node_ms]) counters.
+        # Each thread writes only its own spans' counters (the registry
+        # has no lock)
+        self._counters: Dict[tuple, tuple] = {}
 
     def span(self, name: str, cat: str, track: str, t0_ms: float,
              t1_ms: float, rid: int = -1, cohort: int = -1,
@@ -103,6 +187,78 @@ class Tracer:
         """Lifecycle instant on the request's own track."""
         return self.instant(name, LIFECYCLE, f"req{rid}", t_ms, rid=rid,
                             cohort=cohort, **args)
+
+    # -------------------------------------------------------- host regions
+    def region(self, name: str, **args):
+        """Context manager timing host work on the wall clock (see the
+        module docstring); `args` (such as ``cohort``, ``node``) annotate
+        the profiler event, and a ``node`` also counts into
+        ``host.node_ms{node=,span=}``. Does nothing unless regions are
+        live."""
+        if not self.regions_live:
+            return _OFF
+        return _Region(self, name, args)
+
+    @contextlib.contextmanager
+    def gc_regions(self):
+        """While the block runs, every collection the calling thread
+        makes is a `GC_SPAN` region (nested in whatever region it
+        interrupts). Collections on other threads are not counted."""
+        if not self.regions_live:
+            yield
+            return
+        owner, open_ = threading.get_ident(), []
+
+        def on_gc(phase, _info):
+            if threading.get_ident() != owner:
+                return
+            if phase == "start":
+                open_.append(_Region(self, GC_SPAN, {}).__enter__())
+            elif open_:
+                open_.pop().__exit__(None, None, None)
+
+        gc.callbacks.append(on_gc)
+        try:
+            yield
+        finally:
+            gc.callbacks.remove(on_gc)
+            while open_:
+                open_.pop().__exit__(None, None, None)
+
+    def _stack(self) -> list:
+        st = getattr(self._local, "stack", None)
+        if st is None:
+            st = self._local.stack = []
+        return st
+
+    def _count(self, name: str, ms: float, self_ms: float, node) -> None:
+        c = self._counters.get((name, node))
+        if c is None:
+            m = self.metrics
+            c = (m.counter("host.ms", span=name),
+                 m.counter("host.self_ms", span=name),
+                 m.counter("host.calls", span=name))
+            if node is not None:
+                c += (m.counter("host.node_ms", node=node, span=name),)
+            self._counters[(name, node)] = c
+        c[0].inc(ms)
+        c[1].inc(self_ms)
+        c[2].inc()
+        if node is not None:
+            c[3].inc(ms)
+
+    def host_ms(self) -> Dict[str, float]:
+        """{span: inclusive ms} of every host region counted so far."""
+        # list() copies in one step, safe against the other thread adding
+        return {name: c[0].value
+                for (name, _), c in list(self._counters.items())}
+
+    def node_ms(self, node: int) -> float:
+        """Inclusive ms of drafter node `node`'s work (`NODE_WORK_SPANS`)."""
+        if not self.regions_live:
+            return 0.0
+        return sum(self.metrics.value("host.node_ms", node=node, span=s)
+                   for s in NODE_WORK_SPANS)
 
     # --------------------------------------------------------------- views
     def by_track(self, track: str) -> List[Span]:

@@ -82,6 +82,8 @@ class WallClockExecutor:
         # measured cumulative busy time per stage (observation fracs)
         self._verify_busy_ms = 0.0
         self._draft_busy_ms = 0.0
+        # host-region totals at the last record (its `host_ms` delta)
+        self._host_ms: Dict[str, float] = {}
 
     # --------------------------------------------------------------- state
     def note_dropped(self, rid: int) -> None:
@@ -108,8 +110,8 @@ class WallClockExecutor:
             draft_busy_frac=dfrac,
             queue_depth=queued,
             backlog=backlog,
-            # no per-node wall clocks: the cluster drafts as one host
-            # process, so every node reports the aggregate
+            # the scheduler sees every node at the cluster aggregate: the
+            # cluster drafts as one host process
             drafter_busy_fracs=[dfrac] * n,
             drafter_wait_fracs=[0.0] * n,
             spec_saturated=eng.sched.spec_saturated)
@@ -118,8 +120,13 @@ class WallClockExecutor:
         m.set_gauge("pipeline.draft_busy_frac", obs.draft_busy_frac)
         m.set_gauge("pipeline.queue_depth", obs.queue_depth)
         m.set_gauge("pipeline.backlog", obs.backlog)
-        for i, f in enumerate(obs.drafter_busy_fracs):
-            m.set_gauge("draft.node_busy_frac", f, node=i)
+        # the exported gauge is each node's own measured work (snapshot,
+        # extend and decode regions) over the elapsed time; without host
+        # regions nothing is measured per node and nothing is exported
+        if self.tracer.regions_live:
+            for i in range(n):
+                m.set_gauge("draft.node_busy_frac",
+                            min(self.tracer.node_ms(i) / now, 1.0), node=i)
         return obs
 
     def _observe_conf(self, entries) -> None:
@@ -138,10 +145,11 @@ class WallClockExecutor:
         before this cohort's verification, so the wait (if any) ends
         strictly before the verification does."""
         eng = self.eng
-        for e in entries:
-            fut = self._pending_prefill.pop(e.req.rid, None)
-            if fut is not None:
-                eng.entry_logits[e.req.rid] = fut.result()[e.req.rid][0]
+        with self.tracer.region("engine.resolve"):
+            for e in entries:
+                fut = self._pending_prefill.pop(e.req.rid, None)
+                if fut is not None:
+                    eng.entry_logits[e.req.rid] = fut.result()[e.req.rid][0]
 
     # ------------------------------------------------------------ drafting
     def _spawn(self, prev: Optional[DraftJob]) -> Optional[DraftJob]:
@@ -150,6 +158,7 @@ class WallClockExecutor:
         target prefills are queued asynchronously; drafter prefills run
         here (the drafters' next decode needs them immediately)."""
         eng = self.eng
+        region = self.tracer.region
         inflight = ({e.req.rid: e for e in prev.entries} if prev else {})
         t_now = eng.backend.now_ms()
 
@@ -158,67 +167,71 @@ class WallClockExecutor:
                 return r.arrival_ms
             return eng.avail_ms.get(r.rid, r.arrival_ms)
 
-        everyone = eng.pool.pending(float("inf"))
-        self._gc_prefills({r.rid for r in everyone})
-        cands = [r for r in everyone if avail(r) <= t_now]
-        if not cands and prev is None:
-            if not everyone:
-                return None
-            # real arrival lull: sleep the wall clock to the next arrival
-            t_next = min(avail(r) for r in everyone)
-            if t_next > t_now:
-                time.sleep((t_next - t_now) / 1e3)
-                self._sleeps.append((t_now, eng.backend.now_ms()))
-            t_now = max(eng.backend.now_ms(), t_next)
-            cands = [r for r in everyone if avail(r) <= t_now]
-
         def opt_ext(r):
             e = inflight.get(r.rid)
             return (e.gamma + 1) if e is not None else 0
 
-        cands = [r for r in cands
-                 if r.rid not in inflight
-                 or r.max_new_tokens - len(r.generated) - opt_ext(r) > 0]
-        if not cands:
-            return None
-        obs = self.observation(backlog=len(cands), waiting=prev)
-        if eng.admission is not None:
-            cands = eng._apply_admission(
-                cands, t_now, obs, inflight_rids=frozenset(inflight),
-                pipe_empty=prev is None)
+        with region("engine.plan"):
+            everyone = eng.pool.pending(float("inf"))
+            self._gc_prefills({r.rid for r in everyone})
+            cands = [r for r in everyone if avail(r) <= t_now]
+            if not cands and prev is None:
+                if not everyone:
+                    return None
+                # real arrival lull: sleep the wall clock to the next arrival
+                t_next = min(avail(r) for r in everyone)
+                if t_next > t_now:
+                    with region("engine.lull"):
+                        time.sleep((t_next - t_now) / 1e3)
+                    self._sleeps.append((t_now, eng.backend.now_ms()))
+                t_now = max(eng.backend.now_ms(), t_next)
+                cands = [r for r in everyone if avail(r) <= t_now]
+
+            cands = [r for r in cands
+                     if r.rid not in inflight
+                     or r.max_new_tokens - len(r.generated) - opt_ext(r) > 0]
             if not cands:
                 return None
             obs = self.observation(backlog=len(cands), waiting=prev)
-        cohort = eng._next_cohort()
+            if eng.admission is not None:
+                cands = eng._apply_admission(
+                    cands, t_now, obs, inflight_rids=frozenset(inflight),
+                    pipe_empty=prev is None)
+                if not cands:
+                    return None
+                obs = self.observation(backlog=len(cands), waiting=prev)
+            cohort = eng._next_cohort()
 
         cold = [r for r in cands if r.rid not in eng.entry_logits
                 and r.rid not in self._pending_prefill]
         if cold:
-            for r in cold:
-                if r.n_preemptions > 0 and r.generated:
-                    eng.tracer.mark("readmit", r.rid, t_now)
-            ctxs = {r.rid: list(r.prompt) + r.generated for r in cold}
-            # one masked slot_extend on the verification server, in
-            # flight while we prefill the drafters and draft below
-            fut = eng.backend.prefill_target_async(ctxs)
-            for r in cold:
-                self._pending_prefill[r.rid] = fut
-            lls = eng.backend.prefill_drafters(
-                {rid: c[:-1] for rid, c in ctxs.items()})
-            if eng.strategy == "cosine" and eng.cfg.enable_routing:
-                for rid in ctxs:
-                    eng.router.set_prior(rid, lls[rid])
+            with region("engine.prefill", cohort=cohort):
+                for r in cold:
+                    if r.n_preemptions > 0 and r.generated:
+                        eng.tracer.mark("readmit", r.rid, t_now)
+                ctxs = {r.rid: list(r.prompt) + r.generated for r in cold}
+                # one masked slot_extend on the verification server, in
+                # flight while we prefill the drafters and draft below
+                fut = eng.backend.prefill_target_async(ctxs)
+                for r in cold:
+                    self._pending_prefill[r.rid] = fut
+                lls = eng.backend.prefill_drafters(
+                    {rid: c[:-1] for rid, c in ctxs.items()})
+                if eng.strategy == "cosine" and eng.cfg.enable_routing:
+                    for rid in ctxs:
+                        eng.router.set_prior(rid, lls[rid])
 
-        extra = {r.rid: opt_ext(r) for r in cands if r.rid in inflight}
-        batch, gammas = eng._plan_cohort(cands, observation=obs,
-                                         extra_ctx=extra, now_ms=t_now)
-        optim = {r.rid: inflight[r.rid].d_chains
-                 for r in batch if r.rid in inflight}
-        parts = [eng._participants(r) for r in batch]
+        with region("engine.plan", cohort=cohort):
+            extra = {r.rid: opt_ext(r) for r in cands if r.rid in inflight}
+            batch, gammas = eng._plan_cohort(cands, observation=obs,
+                                             extra_ctx=extra, now_ms=t_now)
+            optim = {r.rid: inflight[r.rid].d_chains
+                     for r in batch if r.rid in inflight}
+            parts = [eng._participants(r) for r in batch]
         rids = tuple(r.rid for r in batch)
         t0 = eng.backend.now_ms()
         entries = eng._draft_entries(batch, gammas, optimistic=optim,
-                                     parts=parts)
+                                     parts=parts, cohort=cohort)
         for e in entries:
             if e.req.rid in optim:
                 e.assumed = [int(t) for t in inflight[e.req.rid].fused_t]
@@ -237,52 +250,54 @@ class WallClockExecutor:
         shift, invalidated requests redraft on the engine thread and the
         redraft's wall time extends the job."""
         eng = self.eng
-        keep, redo, invalid = [], [], []
-        for e in ahead.entries:
-            if e.req.done:
-                continue
-            if e.assumed is None:
-                keep.append(e)
-                continue
-            toks = committed.get(e.req.rid)
-            survives = (toks is not None
-                        and len(toks) == len(e.assumed) + 1
-                        and toks[:-1] == e.assumed
-                        and toks[-1] == int(e.fused_t[0]))
-            if survives:
-                self.n_survived += 1
-                eng.metrics.inc("pipeline.survived")
-                shifted = eng._shift_entry(e)
-                if shifted is not None:
-                    shifted.assumed = None
-                    keep.append(shifted)
+        with self.tracer.region("engine.reconcile", cohort=ahead.cohort):
+            keep, redo, invalid = [], [], []
+            for e in ahead.entries:
+                if e.req.done:
+                    continue
+                if e.assumed is None:
+                    keep.append(e)
+                    continue
+                toks = committed.get(e.req.rid)
+                survives = (toks is not None
+                            and len(toks) == len(e.assumed) + 1
+                            and toks[:-1] == e.assumed
+                            and toks[-1] == int(e.fused_t[0]))
+                if survives:
+                    self.n_survived += 1
+                    eng.metrics.inc("pipeline.survived")
+                    shifted = eng._shift_entry(e)
+                    if shifted is not None:
+                        shifted.assumed = None
+                        keep.append(shifted)
+                    else:
+                        redo.append(e.req)
                 else:
+                    invalid.append(e.req)
                     redo.append(e.req)
-            else:
-                invalid.append(e.req)
-                redo.append(e.req)
-        self.n_invalidated += len(invalid)
-        ahead.entries = keep
-        if invalid:
-            eng.metrics.inc("pipeline.invalidated", len(invalid))
-            for r in invalid:
-                self.tracer.mark("invalidate", r.rid, t_known_ms,
-                                 cohort=ahead.cohort)
-        if redo:
-            gammas = eng._cohort_gammas(redo)
-            parts = [eng._participants(r) for r in redo]
-            t0 = eng.backend.now_ms()
-            redo_entries = eng._draft_entries(redo, gammas, parts=parts)
-            self._observe_conf(redo_entries)
-            t1 = eng.backend.now_ms()
-            self._draft_busy_ms += t1 - t0
-            self.tracer.span("redraft", STAGE, DRAFT, t0, t1,
-                             cohort=ahead.cohort,
-                             rids=tuple(r.rid for r in redo))
-            ahead.entries = keep + redo_entries
-            ahead.draft_ms += t1 - t0
-            ahead.ready_ms = max(ahead.ready_ms, t1)
-            ahead.n_active = max(ahead.n_active, eng.n_active(redo_entries))
+            self.n_invalidated += len(invalid)
+            ahead.entries = keep
+            if invalid:
+                eng.metrics.inc("pipeline.invalidated", len(invalid))
+                for r in invalid:
+                    self.tracer.mark("invalidate", r.rid, t_known_ms,
+                                     cohort=ahead.cohort)
+            if redo:
+                gammas = eng._cohort_gammas(redo)
+                parts = [eng._participants(r) for r in redo]
+                t0 = eng.backend.now_ms()
+                redo_entries = eng._draft_entries(redo, gammas, parts=parts,
+                                                  cohort=ahead.cohort)
+                self._observe_conf(redo_entries)
+                t1 = eng.backend.now_ms()
+                self._draft_busy_ms += t1 - t0
+                self.tracer.span("redraft", STAGE, DRAFT, t0, t1,
+                                 cohort=ahead.cohort,
+                                 rids=tuple(r.rid for r in redo))
+                ahead.entries = keep + redo_entries
+                ahead.draft_ms += t1 - t0
+                ahead.ready_ms = max(ahead.ready_ms, t1)
+                ahead.n_active = max(ahead.n_active, eng.n_active(redo_entries))
         if not ahead.entries:
             return None
         return ahead
@@ -291,7 +306,19 @@ class WallClockExecutor:
     def step(self):
         """One wall-clock serving iteration: draft (or reuse the
         draft-ahead job), dispatch verification, walk acceptance,
-        commit, and spawn the next draft-ahead job."""
+        commit, and spawn the next draft-ahead job. The record carries
+        the host regions' ms since the previous record."""
+        with self.tracer.gc_regions():
+            rec = self._step()
+        if rec is not None and self.tracer.regions_live:
+            tot = self.tracer.host_ms()
+            rec.host_ms = {k: v - self._host_ms.get(k, 0.0)
+                           for k, v in tot.items()
+                           if v > self._host_ms.get(k, 0.0)}
+            self._host_ms = tot
+        return rec
+
+    def _step(self):
         eng = self.eng
         job, self.next_job = self.next_job, None
         if job is None:
@@ -302,7 +329,7 @@ class WallClockExecutor:
         batch = [e.req for e in job.entries]
         big_gamma = sum(e.tree.n_nodes for e in job.entries)
         # verification in flight on the worker from here on
-        handle = eng._verify_dispatch(job.entries)
+        handle = eng._verify_dispatch(job.entries, cohort=job.cohort)
         # draft-ahead on this thread, physically concurrent with it
         ahead = self._spawn(job) if self.overlap else None
         self._resolve_prefills(job.entries)
@@ -365,14 +392,14 @@ class WallClockExecutor:
             verify_start_ms=vstart, verify_ms=t_llm,
             verify_idle_ms=bubble, prefill_ms=prefill_ms,
             queue_depth=queue_depth)
-        eng._finalize(batch, committed, rec)
-
-        if eng.strategy == "cosine":
-            for e in job.entries:
-                if not e.req.done:
-                    eng.sched.update_gamma_feedback(
-                        e.req, len(committed[e.req.rid]), self.busy_ema,
-                        now_ms=vend)
+        with self.tracer.region("engine.finalize", cohort=job.cohort):
+            eng._finalize(batch, committed, rec)
+            if eng.strategy == "cosine":
+                for e in job.entries:
+                    if not e.req.done:
+                        eng.sched.update_gamma_feedback(
+                            e.req, len(committed[e.req.rid]), self.busy_ema,
+                            now_ms=vend)
 
         if ahead is not None:
             n_inv0 = self.n_invalidated

@@ -43,29 +43,39 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.obs.trace import Tracer
 from repro.serving.runner import ModelRunner
+
+# the tracer of a backend no engine has bound: its regions are inert
+_NO_TRACER = Tracer(enabled=False)
 
 
 class VerifyHandle:
     """Lazy verification result. `result()` materializes the (B, Gmax, V)
     logits on the caller; `times()` reports the measured wall span of the
     forward (None under the simulated backend, where the span lives on
-    the simulated verify StageClock instead)."""
+    the simulated verify StageClock instead). The wait for the forward
+    and the host copy are the caller's `engine.verify_wait` and
+    `engine.logits_readback` regions."""
 
     def __init__(self, value: Optional[np.ndarray] = None,
                  future: Optional[Future] = None,
                  convert: Optional[Callable] = None,
-                 span: Optional[dict] = None):
+                 span: Optional[dict] = None,
+                 tracer: Tracer = _NO_TRACER):
         self._value = value
         self._future = future
         self._convert = convert
         self._span = span
+        self._tracer = tracer
 
     def result(self) -> np.ndarray:
         """Materialize (blocking) and cache the verification logits."""
         if self._value is None:
-            raw = self._future.result()
-            self._value = self._convert(raw) if self._convert else raw
+            with self._tracer.region("engine.verify_wait"):
+                raw = self._future.result()
+            with self._tracer.region("engine.logits_readback"):
+                self._value = self._convert(raw) if self._convert else raw
         return self._value
 
     def times(self) -> Optional[Tuple[float, float]]:
@@ -88,6 +98,8 @@ class ExecutionBackend(ABC):
     #: True when `now_ms()` is wall time and model calls may be in
     #: flight concurrently (selects the WallClockExecutor)
     is_wallclock = False
+    #: the bound engine's tracer (host regions of server tasks)
+    tracer: Tracer = _NO_TRACER
 
     def __init__(self, target, drafter_specs, max_len: int,
                  paged: bool = False, page_size: int = 64,
@@ -100,8 +112,10 @@ class ExecutionBackend(ABC):
         self._engine = None
 
     def bind(self, engine):
-        """Attach the engine (clock source for the simulated backend)."""
+        """Attach the engine (clock source for the simulated backend,
+        tracer for the host regions)."""
         self._engine = engine
+        self.tracer = engine.tracer
 
     # ------------------------------------------------------------ clock
     @abstractmethod
@@ -120,9 +134,10 @@ class ExecutionBackend(ABC):
 
     @abstractmethod
     def verify_dispatch(self, rids: Sequence[int], tokens: np.ndarray,
-                        rel_pos: np.ndarray, seg_mask: np.ndarray
-                        ) -> VerifyHandle:
-        """Start a tree verification forward; returns a lazy handle."""
+                        rel_pos: np.ndarray, seg_mask: np.ndarray,
+                        cohort: int = -1) -> VerifyHandle:
+        """Start a tree verification forward; returns a lazy handle.
+        `cohort` only annotates the host region of the forward."""
 
     @abstractmethod
     def commit_target(self, committed: Dict[int, List[int]]
@@ -209,7 +224,7 @@ class SimulatedBackend(ExecutionBackend):
                 out[rid].append(ll)
         return out
 
-    def verify_dispatch(self, rids, tokens, rel_pos, seg_mask):
+    def verify_dispatch(self, rids, tokens, rel_pos, seg_mask, cohort=-1):
         """Run tree verification synchronously; handle is pre-resolved."""
         return VerifyHandle(
             value=self.target.verify(rids, tokens, rel_pos, seg_mask))
@@ -277,15 +292,20 @@ class AsyncJaxBackend(ExecutionBackend):
         return (time.monotonic() - self._t0) * 1e3
 
     # ---------------------------------------------------- target worker
-    def submit_target(self, kind: str, fn: Callable) -> Tuple[Future, dict]:
+    def submit_target(self, kind: str, fn: Callable,
+                      **args) -> Tuple[Future, dict]:
         """Queue `fn` on the verification server thread; returns (future,
-        span) where span's t0/t1 are filled in by the worker."""
+        span) where span's t0/t1 are filled in by the worker. The task is
+        the server thread's ``server.<kind>`` host region, annotated with
+        `args`."""
         span = {"kind": kind, "t0": 0.0, "t1": 0.0}
+        name = f"server.{kind}"
 
         def _task():
             span["t0"] = self.now_ms()
             try:
-                return fn()
+                with self.tracer.region(name, **args):
+                    return fn()
             except BaseException as exc:
                 self._failures.append(exc)
                 raise
@@ -317,7 +337,7 @@ class AsyncJaxBackend(ExecutionBackend):
             "prefill", lambda: self.target.prefill_requests(reqs))
         return fut
 
-    def verify_dispatch(self, rids, tokens, rel_pos, seg_mask):
+    def verify_dispatch(self, rids, tokens, rel_pos, seg_mask, cohort=-1):
         """Queue tree verification on the server thread; lazy handle."""
         B = len(rids)
         vocab = self.target.cfg.vocab
@@ -327,10 +347,11 @@ class AsyncJaxBackend(ExecutionBackend):
             lg.block_until_ready()   # compute timed here; transfer deferred
             return lg
 
-        fut, span = self.submit_target("verify", _fwd)
+        fut, span = self.submit_target("verify", _fwd, cohort=cohort)
         return VerifyHandle(
             future=fut, span=span,
-            convert=lambda lg: np.asarray(lg[:B, :, :vocab]))
+            convert=lambda lg: np.asarray(lg[:B, :, :vocab]),
+            tracer=self.tracer)
 
     def commit_target(self, committed):
         """Blocking cache commit (see `commit_target_async`)."""

@@ -101,6 +101,11 @@ class IterationRecord:
     node_busy_ms: Tuple[float, ...] = ()
     n_straggler_side: int = 0
     n_straggler_dropped: int = 0
+    # wall-clock host regions (obs.trace.HOST_SPANS), inclusive ms spent
+    # in each since the previous record: the host work of this step on
+    # the engine thread and of the tasks the server ran meanwhile. Empty
+    # on the simulated clocks and with tracing off.
+    host_ms: Dict[str, float] = field(default_factory=dict)
 
 
 @dataclass
@@ -315,7 +320,6 @@ class SpeculativeEngine:
             backend, target, drafters, max_len,
             paged=cosine.paged_pool, page_size=cosine.page_size,
             pool_pages=cosine.pool_pages)
-        self.backend.bind(self)
         self.target = self.backend.target
         self.drafters = self.backend.drafters
         self.drafter_domains = [d for _, _, d in drafters]
@@ -324,8 +328,13 @@ class SpeculativeEngine:
         # telemetry (DESIGN.md §2.6): one registry + tracer per engine;
         # the controllers share the registry's decision log
         self.metrics = MetricsRegistry()
+        # host regions time wall-clock work, so only the wall-clock
+        # backend's tracer counts them (obs/trace.py)
         self.tracer = Tracer(enabled=cosine.enable_tracing,
-                             max_spans=cosine.obs_max_events)
+                             max_spans=cosine.obs_max_events,
+                             metrics=(self.metrics if self.backend.is_wallclock
+                                      else None))
+        self.backend.bind(self)
         self.router = AdaptiveRouter(len(self.drafters), cosine,
                                      self.target.embed_np, seed)
         self.sched = RequestScheduler(cosine, self.lat,
@@ -588,8 +597,8 @@ class SpeculativeEngine:
     def _draft_entries(self, batch: List[Request], gammas: List[int],
                        optimistic: Optional[Dict[int, np.ndarray]] = None,
                        parts: Optional[List[List[int]]] = None,
-                       roles: Optional[Dict[int, str]] = None
-                       ) -> List[DraftEntry]:
+                       roles: Optional[Dict[int, str]] = None,
+                       cohort: int = -1) -> List[DraftEntry]:
         """Draft one cohort. `optimistic[rid]` is an (N, n) matrix of
         per-drafter chain tokens assumed to already extend rid's committed
         context (draft-ahead); requests are grouped by assumption width so
@@ -598,24 +607,26 @@ class SpeculativeEngine:
         parts/roles: precomputed per-request participants and per-node
         cluster roles ("fused"/"side"/"dropped") from the drafter
         cluster's timing plan (DESIGN.md §2.4); None means every
-        participant is on time (the coupled baselines)."""
+        participant is on time (the coupled baselines). `cohort` only
+        annotates the host region."""
         optimistic = optimistic or {}
         groups: Dict[int, List[int]] = {}
         for i, r in enumerate(batch):
             n = optimistic[r.rid].shape[1] if r.rid in optimistic else 0
             groups.setdefault(n, []).append(i)
         entries: List[Optional[DraftEntry]] = [None] * len(batch)
-        for n, idxs in sorted(groups.items()):
-            sub = [batch[i] for i in idxs]
-            sub_g = [gammas[i] for i in idxs]
-            sub_p = [parts[i] for i in idxs] if parts is not None else None
-            teach = None
-            if n:
-                teach = np.stack([optimistic[r.rid] for r in sub], axis=1)
-            for i, e in zip(idxs, self._draft_group(sub, sub_g, teach,
-                                                    parts=sub_p,
-                                                    roles=roles)):
-                entries[i] = e
+        with self.tracer.region("engine.draft", cohort=cohort):
+            for n, idxs in sorted(groups.items()):
+                sub = [batch[i] for i in idxs]
+                sub_g = [gammas[i] for i in idxs]
+                sub_p = [parts[i] for i in idxs] if parts is not None else None
+                teach = None
+                if n:
+                    teach = np.stack([optimistic[r.rid] for r in sub], axis=1)
+                for i, e in zip(idxs, self._draft_group(sub, sub_g, teach,
+                                                        parts=sub_p,
+                                                        roles=roles)):
+                    entries[i] = e
         return entries  # type: ignore[return-value]
 
     def _draft_group(self, batch: List[Request], gammas: List[int],
@@ -671,8 +682,12 @@ class SpeculativeEngine:
         # only its routed rids; the snapshots are decoded on and then
         # discarded (= rollback) — the slot-resident caches only advance
         # at commit time.
-        temp = {di: self.backend.draft_snapshot(
-            di, [rids[b] for b in rows_of[di]]) for di in active}
+        region = self.tracer.region
+        temp = {}
+        for di in active:
+            with region("draft.snapshot", node=di):
+                temp[di] = self.backend.draft_snapshot(
+                    di, [rids[b] for b in rows_of[di]])
 
         prev_last = np.array([(r.generated[-1] if r.generated
                                else int(r.prompt[-1])) for r in batch],
@@ -690,7 +705,8 @@ class SpeculativeEngine:
                 t_rows = teach[di][rows]
                 feed = np.concatenate([prev_last[rows][:, None],
                                        t_rows[:, :-1]], axis=1)
-                temp[di] = self.backend.draft_extend(di, temp[di], feed)
+                with region("draft.extend", node=di):
+                    temp[di] = self.backend.draft_extend(di, temp[di], feed)
                 prev_node[di] = t_rows[:, -1].astype(np.int32).copy()
 
         # drafter-compute accounting: each node pays K steps over its own
@@ -709,12 +725,14 @@ class SpeculativeEngine:
             step_confs = np.full((N, B), -1.0, np.float32)
             for di in active:
                 rows = rows_of[di]
-                lg, temp[di] = self.backend.draft_decode(
-                    di, [rids[b] for b in rows], prev_node[di], temp[di])
-                probs = jax.nn.softmax(jnp.asarray(lg), -1)
-                tok = np.asarray(jnp.argmax(probs, -1))
-                conf = np.asarray(jnp.take_along_axis(
-                    probs, jnp.asarray(tok)[:, None], -1))[:, 0]
+                with region("draft.decode", node=di):
+                    lg, temp[di] = self.backend.draft_decode(
+                        di, [rids[b] for b in rows], prev_node[di], temp[di])
+                with region("draft.sample", node=di):
+                    probs = jax.nn.softmax(jnp.asarray(lg), -1)
+                    tok = np.asarray(jnp.argmax(probs, -1))
+                    conf = np.asarray(jnp.take_along_axis(
+                        probs, jnp.asarray(tok)[:, None], -1))[:, 0]
                 step_tokens[di, rows] = tok
                 step_confs[di, rows] = conf
             all_tokens[:, :, i] = step_tokens
@@ -722,33 +740,34 @@ class SpeculativeEngine:
 
             # confidence-based token fusion (Eq. 4), per request over only
             # that request's on-time participants
-            fused = np.zeros(B, np.int32)
-            fused_p = np.zeros(B, np.float32)
-            for b in range(B):
-                cand = fuse_cand[b]
-                masked = np.full(N, -1.0)
-                masked[cand] = step_confs[cand, b]
-                best = int(np.argmax(masked))
-                fused[b] = step_tokens[best, b]
-                fused_p[b] = max(masked[best], 0.0)
-            chain_tokens[:, i] = fused
-            chain_probs[:, i] = fused_p
+            with region("draft.fuse"):
+                fused = np.zeros(B, np.int32)
+                fused_p = np.zeros(B, np.float32)
+                for b in range(B):
+                    cand = fuse_cand[b]
+                    masked = np.full(N, -1.0)
+                    masked[cand] = step_confs[cand, b]
+                    best = int(np.argmax(masked))
+                    fused[b] = step_tokens[best, b]
+                    fused_p[b] = max(masked[best], 0.0)
+                chain_tokens[:, i] = fused
+                chain_probs[:, i] = fused_p
 
-            for di in active:
-                rows = rows_of[di]
-                if fuse:
-                    # cut nodes are out of the per-step sync: they chain
-                    # on their own proposals, not the fused token
-                    if roles.get(di, "fused") == "fused":
-                        prev_node[di] = fused[rows].copy()
-                    else:
+                for di in active:
+                    rows = rows_of[di]
+                    if fuse:
+                        # cut nodes are out of the per-step sync: they chain
+                        # on their own proposals, not the fused token
+                        if roles.get(di, "fused") == "fused":
+                            prev_node[di] = fused[rows].copy()
+                        else:
+                            prev_node[di] = step_tokens[di, rows].copy()
+                    elif self.strategy in ("specinfer", "cosine"):
+                        # independent chains (SpecInfer; no-fusion ablation)
                         prev_node[di] = step_tokens[di, rows].copy()
-                elif self.strategy in ("specinfer", "cosine"):
-                    # independent chains (SpecInfer; no-fusion ablation)
-                    prev_node[di] = step_tokens[di, rows].copy()
-                else:  # single-drafter chain
-                    prev_node[di] = step_tokens[0, rows].copy()
-                d_chains[di, rows, i] = prev_node[di]
+                    else:  # single-drafter chain
+                        prev_node[di] = step_tokens[0, rows].copy()
+                    d_chains[di, rows, i] = prev_node[di]
 
         # (node, request) pairs outside the routed sub-batches consumed no
         # tokens; their teacher-forcing script is the fused chain — the
@@ -763,22 +782,23 @@ class SpeculativeEngine:
         d_chains[ni, bi, :] = chain_tokens[bi, :]
 
         out = []
-        for b, r in enumerate(batch):
-            g = gammas[b]
-            # the token tree only carries chains that physically reached
-            # the server (fused + in-grace side chains); dropped chains
-            # contribute neither branches nor routing evidence
-            tree = self._build_entry_tree(
-                chain_tokens[b, :g], chain_probs[b, :g],
-                all_tokens[:, b, :g], all_confs[:, b, :g], delivered[b], g)
-            out.append(DraftEntry(
-                req=r, gamma=g, tree=tree,
-                fused_t=chain_tokens[b, :g].copy(),
-                fused_p=chain_probs[b, :g].copy(),
-                d_toks=all_tokens[:, b, :g].copy(),
-                d_confs=all_confs[:, b, :g].copy(),
-                d_chains=d_chains[:, b, :g].copy(),
-                parts=delivered[b]))
+        with region("draft.tree"):
+            for b, r in enumerate(batch):
+                g = gammas[b]
+                # the token tree only carries chains that physically reached
+                # the server (fused + in-grace side chains); dropped chains
+                # contribute neither branches nor routing evidence
+                tree = self._build_entry_tree(
+                    chain_tokens[b, :g], chain_probs[b, :g],
+                    all_tokens[:, b, :g], all_confs[:, b, :g], delivered[b], g)
+                out.append(DraftEntry(
+                    req=r, gamma=g, tree=tree,
+                    fused_t=chain_tokens[b, :g].copy(),
+                    fused_p=chain_probs[b, :g].copy(),
+                    d_toks=all_tokens[:, b, :g].copy(),
+                    d_confs=all_confs[:, b, :g].copy(),
+                    d_chains=d_chains[:, b, :g].copy(),
+                    parts=delivered[b]))
         return out
 
     def _shift_entry(self, e: DraftEntry) -> Optional[DraftEntry]:
@@ -797,18 +817,20 @@ class SpeculativeEngine:
                           d_chains=e.d_chains[:, 1:], parts=e.parts)
 
     # ------------------------------------------------------------ verify
-    def _verify_dispatch(self, entries: List[DraftEntry]) -> VerifyHandle:
+    def _verify_dispatch(self, entries: List[DraftEntry],
+                         cohort: int = -1) -> VerifyHandle:
         """Start the batched tree-verification forward for a cohort. On
         the simulated backend the forward runs synchronously here; on the
         async backend it is in flight on the verification server while
         the caller drafts ahead."""
-        trees = [e.tree for e in entries]
-        M_nodes = max(t.n_nodes for t in trees)
-        padded = tree_mod.pad_trees(trees, M_nodes)
-        rids = [e.req.rid for e in entries]
-        return self.backend.verify_dispatch(rids, padded["tokens"],
-                                            padded["rel_pos"],
-                                            padded["mask"])
+        with self.tracer.region("engine.verify_dispatch", cohort=cohort):
+            trees = [e.tree for e in entries]
+            M_nodes = max(t.n_nodes for t in trees)
+            padded = tree_mod.pad_trees(trees, M_nodes)
+            rids = [e.req.rid for e in entries]
+            return self.backend.verify_dispatch(rids, padded["tokens"],
+                                                padded["rel_pos"],
+                                                padded["mask"], cohort=cohort)
 
     def _resolve_tails(self) -> None:
         """Land the pending async commit's tail logits. Rids that left
@@ -819,9 +841,10 @@ class SpeculativeEngine:
         if fut is None:
             return
         self._tails_fut = None
-        for rid, lg in fut.result().items():
-            if rid in self.entry_logits:
-                self.entry_logits[rid] = np.asarray(lg)
+        with self.tracer.region("engine.resolve"):
+            for rid, lg in fut.result().items():
+                if rid in self.entry_logits:
+                    self.entry_logits[rid] = np.asarray(lg)
 
     def _verify_commit(self, entries: List[DraftEntry],
                        handle: Optional[VerifyHandle] = None):
@@ -841,45 +864,47 @@ class SpeculativeEngine:
         # reads entry_logits (async backends defer the commit forward)
         self._resolve_tails()
 
-        prev_last = {r.rid: (r.generated[-1] if r.generated
-                             else int(r.prompt[-1])) for r in batch}
-        committed: Dict[int, List[int]] = {}
-        total_committed = 0
-        for b, (e, r) in enumerate(zip(entries, batch)):
-            t = trees[b]
-            node_argmax = np.argmax(node_logits[b, : t.n_nodes], -1)
-            entry_argmax = int(np.argmax(self.entry_logits[r.rid]))
-            acc_tokens, acc_nodes, correction = tree_mod.accept_tree_greedy(
-                t, node_argmax, entry_argmax)
-            toks = acc_tokens + [int(correction)]
-            remaining = r.max_new_tokens - len(r.generated)
-            toks = toks[: max(remaining, 1)]
-            if self.eos is not None and self.eos in toks:
-                toks = toks[: toks.index(self.eos) + 1]
-            committed[r.rid] = toks
-            total_committed += len(toks)
-            r.record_acceptance(len(toks), e.gamma)
-            # routing update (Eq. 1-2) from this iteration's evidence
-            if self.strategy == "cosine":
-                self.router.update(r.rid, e.d_toks, e.d_confs, toks, e.parts)
+        with self.tracer.region("engine.walk"):
+            prev_last = {r.rid: (r.generated[-1] if r.generated
+                                 else int(r.prompt[-1])) for r in batch}
+            committed: Dict[int, List[int]] = {}
+            total_committed = 0
+            for b, (e, r) in enumerate(zip(entries, batch)):
+                t = trees[b]
+                node_argmax = np.argmax(node_logits[b, : t.n_nodes], -1)
+                entry_argmax = int(np.argmax(self.entry_logits[r.rid]))
+                acc_tokens, acc_nodes, correction = tree_mod.accept_tree_greedy(
+                    t, node_argmax, entry_argmax)
+                toks = acc_tokens + [int(correction)]
+                remaining = r.max_new_tokens - len(r.generated)
+                toks = toks[: max(remaining, 1)]
+                if self.eos is not None and self.eos in toks:
+                    toks = toks[: toks.index(self.eos) + 1]
+                committed[r.rid] = toks
+                total_committed += len(toks)
+                r.record_acceptance(len(toks), e.gamma)
+                # routing update (Eq. 1-2) from this iteration's evidence
+                if self.strategy == "cosine":
+                    self.router.update(r.rid, e.d_toks, e.d_confs, toks, e.parts)
 
         # ---- commit to target + drafters ----
-        if self.backend.is_wallclock:
-            # queue the commit forward on the verification server: it
-            # overlaps the drafter commit + next draft on this thread,
-            # and worker FIFO order guarantees it lands in the target
-            # cache before the next verification reads the slots
-            self._tails_fut = self.backend.commit_target_async(committed)
-        else:
-            tails = self.backend.commit_target(committed)
-            for rid, lg in tails.items():
-                self.entry_logits[rid] = lg
-        if self.drafters:
-            # one-behind invariant: drafters absorb the previously-held-back
-            # token plus all but the last newly committed one
-            d_committed = {rid: [prev_last[rid]] + toks[:-1]
-                           for rid, toks in committed.items()}
-            self.backend.commit_drafters(d_committed)
+        with self.tracer.region("engine.commit"):
+            if self.backend.is_wallclock:
+                # queue the commit forward on the verification server: it
+                # overlaps the drafter commit + next draft on this thread,
+                # and worker FIFO order guarantees it lands in the target
+                # cache before the next verification reads the slots
+                self._tails_fut = self.backend.commit_target_async(committed)
+            else:
+                tails = self.backend.commit_target(committed)
+                for rid, lg in tails.items():
+                    self.entry_logits[rid] = lg
+            if self.drafters:
+                # one-behind invariant: drafters absorb the previously-held-back
+                # token plus all but the last newly committed one
+                d_committed = {rid: [prev_last[rid]] + toks[:-1]
+                               for rid, toks in committed.items()}
+                self.backend.commit_drafters(d_committed)
         return committed, total_committed
 
     # ------------------------------------------------------------ one step
