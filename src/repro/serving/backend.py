@@ -39,10 +39,12 @@ from __future__ import annotations
 import time
 from abc import ABC, abstractmethod
 from concurrent.futures import Future, ThreadPoolExecutor
+from contextlib import contextmanager
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import Tracer
 from repro.serving.runner import ModelRunner
 
@@ -110,12 +112,15 @@ class ExecutionBackend(ABC):
         self.drafters = [ModelRunner(c, p, max_len, **kw)
                          for c, p, _ in drafter_specs]
         self._engine = None
+        #: the bound engine's registry (the draft path's readback bytes)
+        self.metrics = MetricsRegistry()
 
     def bind(self, engine):
         """Attach the engine (clock source for the simulated backend,
-        tracer for the host regions)."""
+        tracer for the host regions, registry for the counters)."""
         self._engine = engine
         self.tracer = engine.tracer
+        self.metrics = engine.metrics
 
     # ------------------------------------------------------------ clock
     @abstractmethod
@@ -153,29 +158,58 @@ class ExecutionBackend(ABC):
         return fut
 
     # ------------------------------------------------------ drafter ops
+    # Drafters run on the engine thread under both backends. What the
+    # draft path copies from drafter `di` to the host is counted as
+    # `draft.readback_bytes{node=di}`.
     @abstractmethod
     def prefill_drafters(self, reqs: Dict[int, Sequence[int]],
                          batched: bool = False) -> Dict[int, List[float]]:
         """One-behind drafter prefill (context WITHOUT its last token);
         returns {rid: per-drafter mean logprobs} (the routing prior)."""
 
-    @abstractmethod
+    @contextmanager
+    def _readback(self, di: int):
+        d = self.drafters[di]
+        before = d.readback_bytes
+        yield
+        self.metrics.inc("draft.readback_bytes", d.readback_bytes - before,
+                         node=di)
+
     def draft_snapshot(self, di: int, rids: Sequence[int]):
         """Speculative slot snapshot for drafter `di` (discard = rollback)."""
+        return self.drafters[di].speculative_caches(rids)
 
-    @abstractmethod
     def draft_extend(self, di: int, snap, tokens: np.ndarray):
         """Teacher-force `tokens` (B, T) into a snapshot (optimistic
-        draft-ahead warm-up); returns the advanced snapshot."""
+        draft-ahead warm-up); returns the advanced snapshot. Its logits
+        are not read."""
+        with self._readback(di):
+            return self.drafters[di].extend_snapshot(snap, tokens)[1]
 
-    @abstractmethod
     def draft_decode(self, di: int, rids: Sequence[int],
                      tokens: np.ndarray, snap):
-        """One drafting step on a snapshot; returns (logits, snapshot)."""
+        """One drafting step on a snapshot; returns (logits, snapshot),
+        the logits (rows, V) left on the device (rows past len(rids) are
+        the bucket's padding)."""
+        return self.drafters[di].decode_device(tokens, snap)
 
-    @abstractmethod
+    def draft_decode_greedy(self, di: int, rids: Sequence[int],
+                            tokens: np.ndarray, snap):
+        """One greedy drafting step: the greedy pick of `draft_decode`'s
+        logits, fetched in one readback. Returns (tokens (B,) int32, their
+        probabilities (B,) float32, snapshot)."""
+        with self._readback(di):
+            lg, snap = self.draft_decode(di, rids, tokens, snap)
+            tok, conf = self.drafters[di].pick(lg)
+        B = len(rids)
+        return tok[:B], conf[:B], snap
+
     def commit_drafters(self, committed: Dict[int, List[int]]) -> None:
-        """Extend every drafter's slot caches (one-behind commit)."""
+        """Extend every drafter's slot caches (one-behind commit); their
+        logits are not read."""
+        for di, d in enumerate(self.drafters):
+            with self._readback(di):
+                d.extend_committed_device(committed)
 
     # -------------------------------------------------------- eviction
     @abstractmethod
@@ -232,23 +266,6 @@ class SimulatedBackend(ExecutionBackend):
     def commit_target(self, committed):
         """Commit accepted tokens into the target cache; returns tails."""
         return self.target.extend_committed(committed)
-
-    def commit_drafters(self, committed):
-        """Commit accepted tokens into every drafter cache."""
-        for d in self.drafters:
-            d.extend_committed(committed)
-
-    def draft_snapshot(self, di, rids):
-        """Rollback-safe speculative cache copy from drafter `di`."""
-        return self.drafters[di].speculative_caches(rids)
-
-    def draft_extend(self, di, snap, tokens):
-        """Teacher-force `tokens` into a drafter snapshot."""
-        return self.drafters[di].extend_snapshot(snap, tokens)[1]
-
-    def draft_decode(self, di, rids, tokens, snap):
-        """One greedy decode step on a drafter snapshot."""
-        return self.drafters[di].decode(rids, tokens, caches=snap)
 
     def drop_request(self, rid):
         """Evict `rid` from the target and every drafter cache."""
@@ -390,23 +407,6 @@ class AsyncJaxBackend(ExecutionBackend):
             for rid in reqs:
                 out[rid].append(res[rid][1])
         return out
-
-    def draft_snapshot(self, di, rids):
-        """Rollback-safe speculative cache copy from drafter `di`."""
-        return self.drafters[di].speculative_caches(rids)
-
-    def draft_extend(self, di, snap, tokens):
-        """Teacher-force `tokens` into a drafter snapshot."""
-        return self.drafters[di].extend_snapshot(snap, tokens)[1]
-
-    def draft_decode(self, di, rids, tokens, snap):
-        """One greedy decode step on a drafter snapshot."""
-        return self.drafters[di].decode(rids, tokens, caches=snap)
-
-    def commit_drafters(self, committed):
-        """Commit accepted tokens into every drafter cache."""
-        for d in self.drafters:
-            d.extend_committed(committed)
 
     def _raise_failure(self):
         if self._failures:

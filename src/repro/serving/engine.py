@@ -43,8 +43,6 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.config import CoSineConfig, ModelConfig
@@ -726,15 +724,11 @@ class SpeculativeEngine:
             for di in active:
                 rows = rows_of[di]
                 with region("draft.decode", node=di):
-                    lg, temp[di] = self.backend.draft_decode(
+                    tok, conf, temp[di] = self.backend.draft_decode_greedy(
                         di, [rids[b] for b in rows], prev_node[di], temp[di])
                 with region("draft.sample", node=di):
-                    probs = jax.nn.softmax(jnp.asarray(lg), -1)
-                    tok = np.asarray(jnp.argmax(probs, -1))
-                    conf = np.asarray(jnp.take_along_axis(
-                        probs, jnp.asarray(tok)[:, None], -1))[:, 0]
-                step_tokens[di, rows] = tok
-                step_confs[di, rows] = conf
+                    step_tokens[di, rows] = tok
+                    step_confs[di, rows] = conf
             all_tokens[:, :, i] = step_tokens
             all_confs[:, :, i] = np.maximum(step_confs, 0.0)
 

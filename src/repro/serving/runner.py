@@ -31,7 +31,7 @@ actually held, and long prompts are not bounded by `max_len`.
 from __future__ import annotations
 
 from functools import partial
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -106,11 +106,24 @@ def slot_bucket(n: int) -> int:
     return 1 << (n - 1).bit_length()
 
 
+def decode_step(params, cfg: ModelConfig, tokens, cache):
+    """`M.decode_step` on a speculative snapshot with its greedy pick in
+    the same program: the logits over the real vocabulary (rows, V), the
+    cache, and each row's argmax token (int32) and its softmax
+    probability, in float32. It keeps the model step's name, by which
+    a device trace finds the drafters' decode programs."""
+    lg, cache, _ = M.decode_step(params, cfg, tokens, cache)
+    x = lg[:, 0, : cfg.vocab]
+    tok = jnp.argmax(x, -1).astype(jnp.int32)
+    conf = jnp.take_along_axis(jax.nn.softmax(x, -1), tok[:, None], -1)[:, 0]
+    return x, cache, tok, conf
+
+
 # Module-level jitted steps with cfg static: every ModelRunner with the
 # same (hashable, frozen) ModelConfig shares one compile cache — engines
 # are created freely in benchmarks without re-tracing. The slotted cache
 # is donated where it is replaced, so XLA updates it in place.
-_g_decode = jax.jit(M.decode_step, static_argnames=("cfg",))
+_g_decode = jax.jit(decode_step, static_argnames=("cfg",))
 _g_extend_plain = jax.jit(M.extend, static_argnames=("cfg",),
                           donate_argnames=("cache",))
 _g_slot_decode = jax.jit(M.slot_decode_step, static_argnames=("cfg",),
@@ -460,6 +473,11 @@ class ModelRunner:
         # masked slot_extend writes issued by the prefill paths (the
         # burst-admission test asserts batched prefill issues fewer)
         self.n_prefill_writes = 0
+        # bytes that decode, pick and the commit's tails copied to the host
+        self.readback_bytes = 0
+        # (logits, token, probability) of the last `decode_device`, on
+        # the device until `pick` fetches them
+        self._pick = None
 
         self._jit_decode = partial(_g_decode, cfg=cfg)
         self._jit_extend_plain = partial(_g_extend_plain, cfg=cfg)
@@ -467,6 +485,14 @@ class ModelRunner:
         self._jit_slot_extend = partial(_g_slot_extend, cfg=cfg)
         self._jit_slot_verify = partial(_g_slot_verify, cfg=cfg)
         self._jit_gather_paged = partial(_g_gather_paged, cfg=cfg)
+
+    def _fetch(self, x):
+        """Copy a device result (an array or a tuple of them, in one
+        transfer) to the host, counted in `readback_bytes`."""
+        out = jax.device_get(x)
+        self.readback_bytes += sum(np.asarray(a).nbytes
+                                   for a in jax.tree.leaves(out))
+        return out
 
     # ----------------------------------------------------------- lifecycle
     def prefill_request(self, rid: int, tokens: np.ndarray):
@@ -602,21 +628,52 @@ class ModelRunner:
         (optimistic draft-ahead warm-up: replays an assumed context
         extension so chaining can continue past it). Exact time shapes
         (no padding along T — SSM-state safe); padded batch rows receive
-        garbage that is never read. Returns (last logits (B, V), caches)."""
-        B = tokens.shape[0]
+        garbage that is never read. Returns (logits (rows, T, padded
+        vocab), caches), both left on the device."""
         rows = int(caches["lengths"].shape[0])
         lg, caches, _ = self._jit_extend_plain(
             self.params,
             tokens=jnp.asarray(self._pad_rows(np.asarray(tokens, np.int32),
                                               rows)),
             cache=caches)
-        return np.asarray(lg[:B, -1, : self.cfg.vocab]), caches
+        return lg, caches
 
     def _pad_rows(self, a: np.ndarray, rows: int) -> np.ndarray:
         if a.shape[0] == rows:
             return a
         pad = np.zeros((rows - a.shape[0],) + a.shape[1:], a.dtype)
         return np.concatenate([a, pad], axis=0)
+
+    def _snapshot_step(self, tokens: np.ndarray, caches: dict):
+        """The `decode_step` program on a snapshot, tokens (B,) padded to
+        its rows: (logits (rows, V), caches, token, probability)."""
+        rows = int(caches["lengths"].shape[0])
+        toks = self._pad_rows(np.asarray(tokens, np.int32), rows)
+        return self._jit_decode(self.params, tokens=jnp.asarray(toks[:, None]),
+                                cache=caches)
+
+    def decode_device(self, tokens: np.ndarray, caches: dict):
+        """One decode step on a speculative snapshot, tokens (B,), with
+        its result left on the device: (logits (rows, V) float32, caches);
+        rows past B are padding. The same program picks each row's greedy
+        token, which `pick` fetches."""
+        lg, caches, tok, conf = self._snapshot_step(tokens, caches)
+        self._pick = (lg, tok, conf)
+        return lg, caches
+
+    def pick(self, logits) -> Tuple[np.ndarray, np.ndarray]:
+        """Each row's greedy token (int32) and its softmax probability
+        (float32), host arrays (rows,). For the logits `decode_device`
+        last returned this is the pick its program computed, fetched in
+        one readback of rows x 8 bytes; any other logits are copied and
+        picked on the host."""
+        held, self._pick = self._pick, None
+        if held is not None and held[0] is logits:
+            return self._fetch(held[1:])
+        x = self._fetch(logits).astype(np.float32)
+        tok = np.argmax(x, -1).astype(np.int32)
+        e = np.exp(x - x.max(-1, keepdims=True))
+        return tok, np.take_along_axis(e, tok[:, None], -1)[:, 0] / e.sum(-1)
 
     def decode(self, rids: Sequence[int], tokens: np.ndarray,
                caches: Optional[dict] = None):
@@ -626,22 +683,17 @@ class ModelRunner:
         B = len(rids)
         toks = np.asarray(tokens, np.int32)
         if caches is not None:
-            rows = int(caches["lengths"].shape[0])
-            lg, new_cache, _ = self._jit_decode(
-                self.params,
-                tokens=jnp.asarray(self._pad_rows(toks, rows))[:, None],
-                cache=caches)
-        else:
-            sidx = self.slots.padded_idx(rids)
-            pv = self.slots.prepare(rids, write=1)
-            lg, self.slots.cache, _ = self._jit_slot_decode(
-                self.params,
-                tokens=jnp.asarray(self._pad_rows(toks, sidx.shape[0]))[:, None],
-                cache=self.slots.cache, slot_idx=sidx, page_view=pv)
-            for r in rids:
-                self.slots.advance(r, 1)
-            new_cache = None
-        return np.asarray(lg[:B, 0, : self.cfg.vocab]), new_cache
+            lg, new_cache, _, _ = self._snapshot_step(toks, caches)
+            return self._fetch(lg)[:B], new_cache
+        sidx = self.slots.padded_idx(rids)
+        pv = self.slots.prepare(rids, write=1)
+        lg, self.slots.cache, _ = self._jit_slot_decode(
+            self.params,
+            tokens=jnp.asarray(self._pad_rows(toks, sidx.shape[0]))[:, None],
+            cache=self.slots.cache, slot_idx=sidx, page_view=pv)
+        for r in rids:
+            self.slots.advance(r, 1)
+        return self._fetch(lg[:B, 0, : self.cfg.vocab]), None
 
     def verify_device(self, rids: Sequence[int], tokens: np.ndarray,
                       rel_pos: np.ndarray, seg_mask: np.ndarray):
@@ -678,11 +730,13 @@ class ModelRunner:
         lg = self.verify_device(rids, tokens, rel_pos, seg_mask)
         return np.asarray(lg[:B, :, : self.cfg.vocab])
 
-    def extend_committed(self, rid_tokens: Dict[int, List[int]]) -> Dict[int, np.ndarray]:
-        """Commit accepted tokens per request into the slotted cache;
-        returns each request's post-commit tail logits (V,). Groups by
-        token-count so shapes stay exact (SSM-state safe)."""
-        out: Dict[int, np.ndarray] = {}
+    def extend_committed_device(self, rid_tokens: Dict[int, List[int]]
+                                ) -> List[Tuple[List[int], jax.Array]]:
+        """Commit accepted tokens per request into the slotted cache.
+        Groups by token-count so shapes stay exact (SSM-state safe);
+        returns each group's rids and its logits (rows, n, padded vocab),
+        left on the device."""
+        out: List[Tuple[List[int], jax.Array]] = []
         by_len: Dict[int, List[int]] = {}
         for rid, toks in rid_tokens.items():
             by_len.setdefault(len(toks), []).append(rid)
@@ -696,9 +750,18 @@ class ModelRunner:
                 self.params,
                 tokens=jnp.asarray(self._pad_rows(toks, int(sidx.shape[0]))),
                 cache=self.slots.cache, slot_idx=sidx, page_view=pv)
-            for i, r in enumerate(rids):
-                out[r] = np.asarray(lg[i, -1, : self.cfg.vocab])
+            for r in rids:
                 self.slots.advance(r, n)
+            out.append((rids, lg))
+        return out
+
+    def extend_committed(self, rid_tokens: Dict[int, List[int]]) -> Dict[int, np.ndarray]:
+        """`extend_committed_device`, returning each request's post-commit
+        tail logits (V,) on the host."""
+        out: Dict[int, np.ndarray] = {}
+        for rids, lg in self.extend_committed_device(rid_tokens):
+            for i, r in enumerate(rids):
+                out[r] = self._fetch(lg[i, -1, : self.cfg.vocab])
         return out
 
     def length(self, rid: int) -> int:

@@ -3,7 +3,7 @@
 The TPU compiler refuses what the Pallas interpreter accepts (block
 shapes off the (8, 128) tiling, programs that overflow HBM), so the five
 kernels at serving widths and the full-width target verify and drafter
-decode steps are compiled here for one v5e chip. Nothing runs: these
+decode steps (on the slotted cache and on a snapshot) are compiled here for one v5e chip. Nothing runs: these
 tests say nothing about results or times.
 
 The topology is described inside a fixture, never at import: only one
@@ -20,7 +20,8 @@ from jax.sharding import SingleDeviceSharding
 import chip_smoke
 from repro.configs import get_config
 from repro.models import model as M
-from repro.serving.runner import _g_slot_decode, _g_slot_extend, _g_slot_verify
+from repro.serving.runner import (_g_decode, _g_slot_decode, _g_slot_extend,
+                                  _g_slot_verify)
 
 HBM_BYTES = 15.75 * 2 ** 30        # v5e HBM the compiler may allocate
 
@@ -83,7 +84,7 @@ def _step_args(cfg, one_chip, tokens):
 
 
 @pytest.mark.parametrize("step", ["target_verify", "target_prefill",
-                                  "drafter_decode"])
+                                  "drafter_decode", "drafter_snapshot_decode"])
 def test_full_width_step_fits_one_chip(one_chip, step):
     """The step alone fits one chip, and a step that writes the cache
     updates it in place: no temporary as large as one cache leaf (a
@@ -102,9 +103,16 @@ def test_full_width_step_fits_one_chip(one_chip, step):
         kw.update(_on(one_chip, dict(
             token_mask=jax.ShapeDtypeStruct((rows, T), jnp.bool_))))
         lowered = _g_slot_extend.lower(cfg=TARGET, page_view=None, **kw)
-    else:
+    elif step == "drafter_decode":
         kw = _step_args(DRAFTER, one_chip, 1)
         lowered = _g_slot_decode.lower(cfg=DRAFTER, **kw)
+    else:
+        # the greedy draft step: a snapshot of the live rows, no slot_idx
+        kw = _step_args(DRAFTER, one_chip, 1)
+        del kw["slot_idx"]
+        kw["cache"] = _on(one_chip, jax.eval_shape(lambda: M.init_cache(
+            DRAFTER, rows, chip_smoke.MAX_LEN, dtype=jnp.dtype(DRAFTER.dtype))))
+        lowered = _g_decode.lower(cfg=DRAFTER, **kw)
     mem = lowered.compile().memory_analysis()
     used = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
