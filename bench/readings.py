@@ -23,7 +23,7 @@ ROOT = Path(__file__).resolve().parents[1]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-from bench import run, traffic  # noqa: E402
+from bench import families, run, traffic  # noqa: E402
 
 
 def main(argv=None) -> int:
@@ -39,7 +39,8 @@ def main(argv=None) -> int:
         cfg = run.load_config(bench, wl["config"])
         mix = traffic.load_mix(wl["traffic"])
         _, peak = run.check_device(int(wl["chips"]))
-    except (run.NoDevice, FileNotFoundError, KeyError) as e:
+    except (run.NoDevice, FileNotFoundError, KeyError,
+            families.ConfigError) as e:
         print(f"readings: {e}", file=sys.stderr)
         return 2
     run.enable_cache()

@@ -1,19 +1,17 @@
-"""Plain reference of the served models: a decoder-only transformer as the
-Qwen1.5/Qwen2/Llama model cards describe it, in float32 `jax.numpy`.
+"""Plain reference of the served models, in float32 `jax.numpy`.
 
-Pre-norm blocks: RMSNorm -> grouped-query attention with rotary position
-(rotate-half form, base `rope_theta`), optional q/k/v bias, causal mask
--> residual; RMSNorm -> SwiGLU MLP -> residual; final RMSNorm and the
-head (the embedding transposed when tied). No cache, no batching tricks,
-no kernels: one full forward over a sequence, layer by layer.
+Each model's forward pass is its family's (`bench/families/<family>.py`
+`forward`): one full forward over a sequence, no cache, no batching
+tricks, no kernels. It reads the family's own weight layout
+(`agreement.canonical_weights`) and imports nothing of the program under
+test. This module holds what every family shares: the matrix product at
+full precision and its fp8 control, RMSNorm and rotary position, and the
+readings that `bench/check.py` compares.
 
-It reads the benchmark's own weight layout (`agreement.canonical_weights`)
-and imports nothing of the program under test.
-
-`precision="fp8"` is the control: the same forward with both operands of
-every matrix product rounded to float8 e4m3 (a scale per row of the
-activations and per output channel of the weights), accumulated in
-float32 - the lower precision that a later change could be tempted by.
+`fp8` is the control: the same forward with both operands of every
+matrix product rounded to float8 e4m3 (a scale per row of the activations
+and per output channel of the weights), accumulated in float32 - the
+lower precision that a later change could be tempted by.
 """
 from __future__ import annotations
 
@@ -21,6 +19,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+
+from bench import families
 
 HIGHEST = jax.lax.Precision.HIGHEST
 E4M3_MAX = 448.0
@@ -56,67 +56,24 @@ def _rope(x, pos, theta):
     return x * cos + rot * sin
 
 
-def _layer(x, w, m, fp8):
-    T = x.shape[0]
-    hq, hkv, hd = m["n_heads"], m["n_kv_heads"], m["head_dim"]
-    f = lambda a: a.astype(jnp.float32)
-    pos = jnp.arange(T)
-    h = _rms(x, f(w["ln1"]), m["norm_eps"])
-    q = _mm(h, f(w["wq"]), fp8)
-    k = _mm(h, f(w["wk"]), fp8)
-    v = _mm(h, f(w["wv"]), fp8)
-    if m["qkv_bias"]:
-        q, k, v = q + f(w["bq"]), k + f(w["bk"]), v + f(w["bv"])
-    q = _rope(q.reshape(T, hq, hd), pos, m["rope_theta"])
-    k = _rope(k.reshape(T, hkv, hd), pos, m["rope_theta"])
-    v = v.reshape(T, hkv, hd)
-    g = hq // hkv
-    k = jnp.repeat(k, g, axis=1)
-    v = jnp.repeat(v, g, axis=1)
-    if fp8:
-        q, k, v = _fp8(q, -1), _fp8(k, -1), _fp8(v, -1)
-    s = jnp.einsum("thd,shd->hts", q, k, precision=HIGHEST) * hd ** -0.5
-    s = jnp.where(pos[None, :, None] >= pos[None, None, :], s, -jnp.inf)
-    p = jax.nn.softmax(s, axis=-1)
-    if fp8:
-        p = _fp8(p, -1)
-    o = jnp.einsum("hts,shd->thd", p, v, precision=HIGHEST)
-    x = x + _mm(o.reshape(T, hq * hd), f(w["wo"]), fp8)
-    h = _rms(x, f(w["ln2"]), m["norm_eps"])
-    a = jax.nn.silu(_mm(h, f(w["wg"]), fp8)) * _mm(h, f(w["wu"]), fp8)
-    return x + _mm(a, f(w["wd"]), fp8)
-
-
 def _model_key(m):
-    return tuple(sorted((k, m[k]) for k in (
-        "n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim", "d_ff",
-        "vocab", "qkv_bias", "tie_embeddings", "rope_theta", "norm_eps")))
+    """The hashable key of one model entry: its family's keys."""
+    return tuple(sorted((k, m[k]) for k in families.of(m).REQUIRED))
 
 
-@partial(jax.jit, static_argnames=("mkey", "fp8"))
-def _logits(weights, tokens, *, mkey, fp8):
-    m = dict(mkey)
-    f = lambda a: a.astype(jnp.float32)
-    x = f(weights["embed"][tokens])
-
-    def body(x, w):
-        return _layer(x, w, m, fp8), None
-
-    x, _ = jax.lax.scan(body, x, weights["layers"])
-    x = _rms(x, f(weights["final_norm"]), m["norm_eps"])
-    head = (weights["embed"].T if m["tie_embeddings"]
-            else weights["head"])[:, : m["vocab"]]
-    return _mm(x, f(head), fp8)
+@partial(jax.jit, static_argnames=("family", "mkey", "fp8"))
+def _logits(weights, tokens, *, family, mkey, fp8):
+    return families.family(family).forward(weights, dict(mkey), tokens, fp8)
 
 
-@partial(jax.jit, static_argnames=("mkey", "control"))
-def _readings(weights, tokens, pick, *, mkey, control):
-    ref = _logits(weights, tokens, mkey=mkey, fp8=False)
+@partial(jax.jit, static_argnames=("family", "mkey", "control"))
+def _readings(weights, tokens, pick, *, family, mkey, control):
+    ref = _logits(weights, tokens, family=family, mkey=mkey, fp8=False)
     best = jnp.max(ref, -1)
     picked = jnp.take_along_axis(ref, jnp.maximum(pick, 0)[:, None], -1)[:, 0]
     out = {"best": best, "picked": picked, "argmax": jnp.argmax(ref, -1)}
     if control:
-        low = _logits(weights, tokens, mkey=mkey, fp8=True)
+        low = _logits(weights, tokens, family=family, mkey=mkey, fp8=True)
         a = jnp.argmax(low, -1)
         out["control_gap"] = best - jnp.take_along_axis(ref, a[:, None],
                                                         -1)[:, 0]
@@ -130,8 +87,8 @@ def readings(weights, model: dict, tokens, pick, control: bool = False):
     the fp8 control puts first. Pad `tokens` to a fixed length so that one
     program serves every request (the mask is causal)."""
     out = _readings(weights, jnp.asarray(tokens, jnp.int32),
-                    jnp.asarray(pick, jnp.int32), mkey=_model_key(model),
-                    control=control)
+                    jnp.asarray(pick, jnp.int32), family=model["family"],
+                    mkey=_model_key(model), control=control)
     return jax.device_get(out)
 
 

@@ -2,12 +2,16 @@
 
 Counts use the published vocabulary (never the padded one) and only the
 work a call needs: no padding rows, no rejected or padded tree nodes. A
-matrix product of (m, k) by (k, n) is 2mkn operations.
+matrix product of (m, k) by (k, n) is 2mkn operations. Each model entry's
+family counts its own (`bench/families/<family>.py`); this module holds
+the chip's peaks and dispatches.
 """
 from __future__ import annotations
 
 import json
 from pathlib import Path
+
+from bench import families
 
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
 
@@ -20,38 +24,32 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
+def least_seconds(flops: float, nbytes: float, peak: dict):
+    """(seconds, bound) of a call that must do `flops` operations and move
+    `nbytes` bytes: bound 'compute' or 'memory', whichever takes longer."""
+    t_c = flops / peak["bf16_flops_per_s"]
+    t_m = nbytes / peak["hbm_bytes_per_s"]
+    return (t_c, "compute") if t_c >= t_m else (t_m, "memory")
+
+
 def layer_params(m: dict) -> int:
-    """Parameters of one decoder layer (attention, biases, MLP, norms)."""
-    d, hq, hkv, hd, ff = (m["d_model"], m["n_heads"], m["n_kv_heads"],
-                          m["head_dim"], m["d_ff"])
-    attn = d * hq * hd * 2 + d * hkv * hd * 2
-    bias = (hq * hd + 2 * hkv * hd) if m["qkv_bias"] else 0
-    return attn + bias + 3 * d * ff + 2 * d
+    """Parameters of one decoder layer, for a family whose layers are all
+    alike."""
+    return families.of(m).layer_params(m)
 
 
 def weight_bytes(m: dict, dtype_bytes: int = 2) -> int:
-    """Bytes of every weight a forward reads once: layers, final norm and
-    head (the embedding rows a call gathers are negligible)."""
-    return dtype_bytes * (m["n_layers"] * layer_params(m) + m["d_model"]
-                          + m["d_model"] * m["vocab"])
+    """Bytes of the weights one forward call reads."""
+    return families.of(m).weight_bytes(m, dtype_bytes)
 
 
 def token_flops(m: dict, context: int) -> float:
     """Forward operations for one token at position `context` (it attends
-    to context + 1 keys): the layers' matrix products, attention scores
-    and values, and the head over the published vocabulary."""
-    d, hq, hkv, hd, ff = (m["d_model"], m["n_heads"], m["n_kv_heads"],
-                          m["head_dim"], m["d_ff"])
-    mats = 2 * (d * hq * hd * 2 + d * hkv * hd * 2 + 3 * d * ff)
-    attn = 4 * hq * hd * (context + 1)
-    return m["n_layers"] * (mats + attn) + 2 * d * m["vocab"]
+    to context + 1 keys), the head over the published vocabulary."""
+    return families.of(m).token_flops(m, context)
 
 
 def call_least_seconds(m: dict, tokens_at: list, peak: dict):
     """Least time of one forward over tokens at the given positions:
-    (seconds, bound) with bound 'compute' or 'memory' (the weights read
-    once, the only bytes every such call must move)."""
-    flops = sum(token_flops(m, c) for c in tokens_at)
-    t_c = flops / peak["bf16_flops_per_s"]
-    t_m = weight_bytes(m) / peak["hbm_bytes_per_s"]
-    return (t_c, "compute") if t_c >= t_m else (t_m, "memory")
+    (seconds, bound) with bound 'compute' or 'memory'."""
+    return families.of(m).call_least_seconds(m, tokens_at, peak)

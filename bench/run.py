@@ -41,7 +41,8 @@ if str(ROOT) not in sys.path:
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from bench import check, roofline, serve, trace, traffic  # noqa: E402
+from bench import (check, families, roofline, serve, trace,  # noqa: E402
+                   traffic)
 
 OUT = ROOT / "bench" / ".out"
 CACHE = ROOT / ".jax_cache"
@@ -57,10 +58,14 @@ def load_benchmark(root: Path = ROOT) -> dict:
 
 
 def load_config(bench: dict, name: str, root: Path = ROOT) -> dict:
-    """A configuration by name, from the file BENCHMARK.json gives it."""
+    """A configuration by name, from the file BENCHMARK.json gives it; each
+    model entry has to name a family that loads and hold its keys."""
     for c in bench["configs"]:
         if c["name"] == name:
-            return json.loads((root / c["file"]).read_text())
+            cfg = json.loads((root / c["file"]).read_text())
+            for m in [cfg["target"]] + [d["model"] for d in cfg["drafters"]]:
+                families.check_entry(m, c["file"])
+            return cfg
     raise KeyError(f"no configuration {name!r} in BENCHMARK.json")
 
 
@@ -283,7 +288,8 @@ def main(argv=None) -> int:
         cfg = load_config(bench, wl["config"])
         mix = traffic.load_mix(wl["traffic"])
         _, peak = check_device(int(wl["chips"]))
-    except (NoDevice, FileNotFoundError, KeyError) as e:
+    except (NoDevice, FileNotFoundError, KeyError,
+            families.ConfigError) as e:
         print(f"bench: {e}", file=sys.stderr)
         return 2
     log_cache = enable_cache()

@@ -84,7 +84,7 @@ def test_shapes_and_dtypes_equal_init_params(which, request):
         pc = program.model_config(m)
         want = jax.eval_shape(lambda k: M.init_params(k, pc),
                               jax.random.PRNGKey(0))
-        got = program.program_params(c, bool(m["tie_embeddings"]))
+        got = program.program_params(c, m)
         assert jax.tree.structure(want) == jax.tree.structure(got)
         for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
             assert a.shape == b.shape and a.dtype == b.dtype

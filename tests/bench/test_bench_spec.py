@@ -1,6 +1,6 @@
 """Everything BENCHMARK.json names loads by name: each configuration file,
-each traffic mix and each per-layer metric's reader; and the file keeps
-the shape the benchmark's contract gives it."""
+each model family, each traffic mix and each per-layer metric's reader;
+and the file keeps the shape the benchmark's contract gives it."""
 import json
 import os
 import re
@@ -11,7 +11,7 @@ sys.path.insert(0, ROOT)
 
 import pytest
 
-from bench import agreement, run, traffic
+from bench import agreement, families, run, traffic
 
 with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
     BENCH = json.load(f)
@@ -24,15 +24,37 @@ def test_config_loads_by_name(c):
     cfg = run.load_config(BENCH, c["name"], root=__import__("pathlib").Path(ROOT))
     assert cfg["name"] == c["name"]
     for m in [cfg["target"]] + agreement.drafter_list(cfg):
-        for k in ("n_layers", "d_model", "n_heads", "n_kv_heads", "head_dim",
-                  "d_ff", "vocab"):
-            assert int(m[k]) > 0
+        # every size, rate and scale a family requires is positive
+        for k in families.of(m).REQUIRED:
+            v = m[k]
+            assert isinstance(v, (bool, str)) or v > 0, (k, v)
     doms = cfg["agreement"]["domains"]
     assert doms[0][0] == 0 and doms[-1][1] == cfg["target"]["vocab"]
     assert all(a[1] == b[0] for a, b in zip(doms, doms[1:]))
     # every limit that the verdict reads
     for k in ("target_gap", "drafter_gap"):
         assert cfg["correct"][k] > 0
+
+
+CONFIG_FILES = sorted(
+    [os.path.join("bench", "configs", n)
+     for n in os.listdir(os.path.join(ROOT, "bench", "configs"))]
+    + [os.path.join("tests", "bench", "tiny_config.json")])
+
+
+@pytest.mark.parametrize("path", CONFIG_FILES)
+def test_every_model_entry_names_a_family_that_loads(path):
+    """Every configuration file, with or without a cell: each model entry
+    names its family, which loads with its adapter, and holds the
+    family's keys."""
+    with open(os.path.join(ROOT, path)) as f:
+        cfg = json.load(f)
+    for m in [cfg["target"]] + [d["model"] for d in cfg["drafters"]]:
+        families.check_entry(m, path)
+        assert set(families.of(m).REQUIRED) <= set(m)
+        adapter = families.adapter(m["family"])
+        assert callable(adapter.model_config)
+        assert callable(adapter.program_params)
 
 
 @pytest.mark.parametrize("w", BENCH["workloads"], ids=lambda w: w["name"])
